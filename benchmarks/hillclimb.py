@@ -7,8 +7,7 @@ import argparse
 import json
 import os
 
-# the dry-run flag must be set before jax init — import dryrun first.
-from repro.launch import dryrun as dr  # noqa: E402  (sets XLA_FLAGS)
+from repro.launch import dryrun as dr
 
 CELLS = {
     # memory-dominated, paper-representative (MoE): microbatch accumulation
@@ -99,6 +98,7 @@ if __name__ == "__main__":
     ap.add_argument("--cell", required=True, choices=list(CELLS) + ["all"])
     ap.add_argument("--out", default="reports/hillclimb")
     a = ap.parse_args()
+    dr.use_fake_host_devices()
     for c in (CELLS if a.cell == "all" else [a.cell]):
         run(c, a.out)
 

@@ -26,6 +26,7 @@ import numpy as np
 from repro.api import (
     Arrival, GeoJob, GeoPipeline, GeoSchedule, OnlineConfig, split_sources,
 )
+from repro.compile_cache import compile_cache_off
 from repro.core.makespan import BARRIERS_GGL
 from repro.core.optimize import (
     optimize_plan,
@@ -638,9 +639,10 @@ def bench_planner() -> Dict:
     opts = dict(n_restarts=n_restarts, steps=steps)
 
     reset_solver_cache_stats()
-    t0 = time.perf_counter()
-    optimize_plan(p, "e2e_multi", seed=0, **opts)
-    cold_s = time.perf_counter() - t0
+    with compile_cache_off():  # nor may the persistent cache serve it
+        t0 = time.perf_counter()
+        optimize_plan(p, "e2e_multi", seed=0, **opts)
+        cold_s = time.perf_counter() - t0
 
     warm_lat = []
     for s in range(1, 9):
@@ -820,6 +822,61 @@ def bench_scale() -> Dict:
     return out
 
 
+def online_brownout(
+    n_regions: int = 12, edges_per_region: int = 40,
+    mappers_per_region: int = 28, n_backbone: int = 4,
+    reducers_per_backbone: int = 45, n_jobs: int = 100,
+) -> Dict:
+    """The scale tier's online scenario: a seeded 3-tier substrate (996
+    nodes at the defaults) whose first backbone's reducers brown out to 5%
+    at t=250 s, and a fluid-mode job mix whose last tenth of releases
+    stream in at t=300 s and t=480 s.  Returns the planned ``schedule``,
+    its ``arrivals``, per-job ``cfgs``, the pinned ``reactive_shared``
+    ``online`` config, ``n_nodes`` and ``n_jobs``."""
+    from repro.core.topology import scale_job_mix, scale_tier_substrate
+
+    sub0 = scale_tier_substrate(
+        n_regions=n_regions, edges_per_region=edges_per_region,
+        mappers_per_region=mappers_per_region, n_backbone=n_backbone,
+        reducers_per_backbone=reducers_per_backbone, seed=1,
+    )
+    cluster_r = np.asarray(sub0.cluster_r)
+    browned = np.flatnonzero(cluster_r == cluster_r[0])
+    C_r = np.asarray(sub0.C_r)
+    sub = sub0.with_traces({
+        f"reduce[r{k}]": CapacityTrace.step(
+            float(C_r[k]), float(C_r[k]) * 0.05, 250.0)
+        for k in browned
+    })
+    entries = scale_job_mix(
+        sub, n_jobs=n_jobs, seed=3, arrival_spread_s=600.0,
+        base_cfg=SimConfig(mode="fluid"),
+    )
+    n_stream = n_jobs // 10
+    order = np.argsort([c.start_time for _, _, c in entries])
+    jobs, cfgs = [], []
+    for i in order[:n_jobs - n_stream]:
+        pv, pl, c = entries[int(i)]
+        jobs.append(GeoJob(pv).with_plan(pl, c.barriers))
+        cfgs.append(c)
+    arrivals = []
+    for n, i in enumerate(order[n_jobs - n_stream:]):
+        pv, pl, c = entries[int(i)]
+        arrivals.append(Arrival(GeoJob(pv).with_plan(pl, c.barriers),
+                                300.0 if n < n_stream // 2 else 480.0, cfg=c))
+    return {
+        "schedule": GeoSchedule(jobs).with_plans(),
+        "arrivals": arrivals,
+        "cfgs": cfgs,
+        # pinned decision cost: measured-EMA charges would make the
+        # swap/keep sequence (and the gated makespan) host-dependent
+        "online": OnlineConfig(shared=True, hysteresis=1.0,
+                               incremental=True, solver_cost_s=5.0),
+        "n_nodes": sub.nS + sub.nM + sub.nR,
+        "n_jobs": len(entries),
+    }
+
+
 def bench_scale_online() -> Dict:
     """Online control at the scale tier (ROADMAP §3): steered vectorized
     drains, fluid capacity traces, and a 1000-node online run.
@@ -910,43 +967,12 @@ def bench_scale_online() -> Dict:
     rel_err_pct = 100.0 * max(rel_errs.values())
 
     # -- 1000-node tier: online control under a backbone brownout ----------
-    sub1k0 = scale_tier_substrate(
-        n_regions=12, edges_per_region=40, mappers_per_region=28,
-        n_backbone=4, reducers_per_backbone=45, seed=1,
-    )
-    cluster_r = np.asarray(sub1k0.cluster_r)
-    browned = np.flatnonzero(cluster_r == cluster_r[0])
-    C_r = np.asarray(sub1k0.C_r)
-    sub1k = sub1k0.with_traces({
-        f"reduce[r{k}]": CapacityTrace.step(
-            float(C_r[k]), float(C_r[k]) * 0.05, 250.0)
-        for k in browned
-    })
-    n_nodes_1k = sub1k.nS + sub1k.nM + sub1k.nR
-    entries_1k = scale_job_mix(
-        sub1k, n_jobs=100, seed=3, arrival_spread_s=600.0,
-        base_cfg=SimConfig(mode="fluid"),
-    )
-    # last 10 releases become true streaming arrivals at two instants
-    order = np.argsort([c.start_time for _, _, c in entries_1k])
-    jobs_1k, cfgs = [], []
-    for i in order[:90]:
-        pv, pl, c = entries_1k[int(i)]
-        jobs_1k.append(GeoJob(pv).with_plan(pl, c.barriers))
-        cfgs.append(c)
-    arrivals = []
-    for n, i in enumerate(order[90:]):
-        pv, pl, c = entries_1k[int(i)]
-        arrivals.append(Arrival(GeoJob(pv).with_plan(pl, c.barriers),
-                                300.0 if n < 5 else 480.0, cfg=c))
-    sched = GeoSchedule(jobs_1k).with_plans()
+    scenario = online_brownout()
+    n_nodes_1k = scenario["n_nodes"]
     t0 = time.perf_counter()
-    report = sched.run_online(
-        policy="reactive_shared", arrivals=arrivals, cfg=cfgs, **_OPT,
-        # pinned decision cost: measured-EMA charges would make the
-        # swap/keep sequence (and the gated makespan) host-dependent
-        online=OnlineConfig(shared=True, hysteresis=1.0, incremental=True,
-                            solver_cost_s=5.0),
+    report = scenario["schedule"].run_online(
+        policy="reactive_shared", arrivals=scenario["arrivals"],
+        cfg=scenario["cfgs"], online=scenario["online"], **_OPT,
     )
     wall_1k = time.perf_counter() - t0
     decisions_per_s = len(report.decisions) / wall_1k if wall_1k else 0.0
@@ -968,7 +994,7 @@ def bench_scale_online() -> Dict:
         },
         "online_1000": {
             "n_nodes": n_nodes_1k,
-            "n_jobs": len(entries_1k),
+            "n_jobs": scenario["n_jobs"],
             "makespan": report.makespan_online,
             "static_makespan": report.makespan_static,
             "online_margin": report.improvement,

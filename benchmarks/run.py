@@ -24,6 +24,8 @@ import os
 import subprocess
 import time
 
+from repro.compile_cache import use_compile_cache
+
 from . import paper_figures as F
 from .common import flush_csv
 
@@ -78,6 +80,7 @@ def main() -> None:
                          "found")
     ap.add_argument("--out", default="reports")
     args = ap.parse_args()
+    use_compile_cache()
     if args.quick:
         F._OPT = dict(n_restarts=6, steps=200)
     os.makedirs(args.out, exist_ok=True)

@@ -303,14 +303,9 @@ def _counted_solver(static_argnames: Tuple[str, ...] = ()):
             else:
                 _SOLVER_STATS["misses"] += 1
                 _SOLVER_KEYS.add(key)
-            size_fn = getattr(jitted, "_cache_size", None)
-            before = size_fn() if callable(size_fn) else None
+            before = jitted._cache_size()
             out = jitted(*args, **kwargs)
-            if before is not None:
-                compiled = size_fn() > before
-            else:  # pragma: no cover — jax without _cache_size()
-                compiled = key not in _SOLVER_KEYS
-            if compiled:
+            if jitted._cache_size() > before:
                 _SOLVER_STATS["compiles"] += 1
             return out
 

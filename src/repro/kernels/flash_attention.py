@@ -21,6 +21,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from . import interpret_mode
+
 __all__ = ["flash_attention"]
 
 _NEG_INF = float("-inf")
@@ -115,7 +117,7 @@ def flash_attention(
     if scale is None:
         scale = Dh**-0.5
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = interpret_mode()
 
     bq = min(block_q, T)
     bk = min(block_k, S)

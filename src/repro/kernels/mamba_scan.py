@@ -21,6 +21,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import interpret_mode
+
 __all__ = ["mamba_scan"]
 
 
@@ -80,7 +82,7 @@ def mamba_scan(
     B, T, Di = x.shape
     Ds = A.shape[1]
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = interpret_mode()
     ck = min(chunk, T)
     bd = min(block_d, Di)
     assert Di % bd == 0, (Di, bd)

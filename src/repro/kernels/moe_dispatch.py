@@ -22,6 +22,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from . import interpret_mode
+
 __all__ = ["moe_dispatch", "compute_slots"]
 
 
@@ -69,7 +71,7 @@ def moe_dispatch(
     """Dispatch; semantics = ref.moe_dispatch_ref.  Returns (E, C, D)."""
     T, D = tokens.shape
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = interpret_mode()
     bt = min(block_t, T)
     Tp = -(-T // bt) * bt
     if Tp != T:

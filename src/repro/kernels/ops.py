@@ -13,7 +13,7 @@ from typing import Optional, Tuple
 
 import jax.numpy as jnp
 
-from . import ref
+from . import interpret_mode, ref
 from .flash_attention import flash_attention
 from .mamba_scan import mamba_scan
 from .moe_dispatch import compute_slots, moe_dispatch
@@ -25,6 +25,7 @@ __all__ = [
     "ssm_scan",
     "gated_linear_recurrence",
     "sorted_segment_sum",
+    "segment_sum_path",
     "dispatch_tokens",
     "combine_tokens",
     "compute_slots",
@@ -69,9 +70,19 @@ def gated_linear_recurrence(x, a, h0=None, use_kernel: bool = True,
     return ref.rglru_scan_ref(x, a, h0)
 
 
+def segment_sum_path(n_rows: int, use_kernel: bool = True) -> str:
+    """What :func:`sorted_segment_sum` runs for ``n_rows`` rows:
+    ``"pallas"`` (compiled for the TPU), ``"pallas-interpret"`` (the CPU
+    test backend) or ``"reference"`` (below the kernel's row floor, or
+    ``use_kernel=False``)."""
+    if not use_kernel or n_rows < _MIN_KERNEL_SEQ:
+        return "reference"
+    return "pallas-interpret" if interpret_mode() else "pallas"
+
+
 def sorted_segment_sum(values, segment_ids, num_segments: int,
                        use_kernel: bool = True, block_n: int = 512):
-    if use_kernel and values.shape[0] >= _MIN_KERNEL_SEQ:
+    if segment_sum_path(values.shape[0], use_kernel) != "reference":
         return segment_sum(values, segment_ids, num_segments, block_n=block_n)
     return ref.segment_sum_ref(values, segment_ids, num_segments)
 

@@ -19,6 +19,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import interpret_mode
+
 __all__ = ["rglru_scan"]
 
 
@@ -61,7 +63,7 @@ def rglru_scan(
     """
     B, T, D = x.shape
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = interpret_mode()
     ck = min(chunk, T)
     bd = min(block_d, D)
     assert D % bd == 0, (D, bd)

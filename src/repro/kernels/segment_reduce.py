@@ -5,11 +5,12 @@ loop is a segment sum.  A GPU implementation would use warp ballots /
 shared-memory atomics; the TPU-native adaptation turns the scatter-add into
 an **MXU one-hot matmul**: for each VMEM block of rows we build the one-hot
 partition matrix ``P[n, s] = (ids[n] == s)`` with ``broadcasted_iota`` and
-accumulate ``Pᵀ @ values`` into a VMEM-resident output block across the
-sequential grid dimension.  No atomics, no data-dependent control flow —
-just dense systolic work.
+accumulate ``valuesᵀ @ P`` (a ``(D, S)`` block) into a VMEM-resident
+output block across the sequential grid dimension.  No atomics, no
+data-dependent control flow — just dense systolic work.
 
-TARGET: TPU.  VALIDATED: ``interpret=True`` vs ref.segment_sum_ref.
+TARGET: TPU.  VALIDATED: ``interpret=True`` vs ref.segment_sum_ref;
+compiled for a described v5e in ``tests/test_tpu_compile.py``.
 """
 from __future__ import annotations
 
@@ -20,7 +21,14 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from . import interpret_mode
+
 __all__ = ["segment_sum"]
+
+#: Elements of the (block_n, num_segments) one-hot block.  With the fp32
+#: contraction's working copies it must fit v5e's 16 MiB scoped VMEM, so
+#: many segments shrink the row block instead.
+_ONEHOT_ELEMS = 1 << 20
 
 
 def _segsum_kernel(v_ref, id_ref, o_ref, *, block_n, num_segments):
@@ -34,8 +42,11 @@ def _segsum_kernel(v_ref, id_ref, o_ref, *, block_n, num_segments):
     ids = id_ref[...]  # (bn, 1) int32
     seg = jax.lax.broadcasted_iota(jnp.int32, (block_n, num_segments), 1)
     onehot = (ids == seg).astype(jnp.float32)  # (bn, S)
+    # fp32 contraction: a bf16 pass would round the summed values (word
+    # counts past 256 stop being exact)
     o_ref[...] += jax.lax.dot_general(
-        onehot, vals, (((0,), (0,)), ((), ())),
+        vals, onehot, (((0,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32,
     )
 
@@ -54,8 +65,10 @@ def segment_sum(
     for correctness, but sorted runs are the intended/benchmarked case)."""
     N, D = values.shape
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    bn = min(block_n, N)
+        interpret = interpret_mode()
+    # largest power-of-two row block whose one-hot fits the budget
+    fit = 1 << max(3, (_ONEHOT_ELEMS // max(num_segments, 1)).bit_length() - 1)
+    bn = min(block_n, fit, N)
     Np = -(-N // bn) * bn
     if Np != N:
         values = jnp.pad(values, ((0, Np - N), (0, 0)))
@@ -74,8 +87,10 @@ def segment_sum(
             pl.BlockSpec((bn, D), lambda i: (i, 0)),
             pl.BlockSpec((bn, 1), lambda i: (i, 0)),
         ],
-        out_specs=pl.BlockSpec((num_segments, D), lambda i: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((num_segments, D), jnp.float32),
+        # (D, S): segments on the lanes, so a narrow D pads to 8 sublanes
+        # rather than to 128 lanes
+        out_specs=pl.BlockSpec((D, num_segments), lambda i: (0, 0)),
+        out_shape=jax.ShapeDtypeStruct((D, num_segments), jnp.float32),
         interpret=interpret,
     )(values, segment_ids.astype(jnp.int32).reshape(-1, 1))
-    return out.astype(values.dtype)
+    return out.T.astype(values.dtype)

@@ -1,10 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = (
-    "--xla_force_host_platform_device_count=512 "
-    + os.environ.get("XLA_FLAGS", "")
-)
-# ^ MUST precede any jax import/init: jax locks the device count on first use.
-
 """Multi-pod dry-run: ``.lower().compile()`` every (arch × shape × mesh).
 
 For each cell this script
@@ -28,6 +21,7 @@ Usage::
 import argparse
 import functools
 import json
+import os
 import re
 import time
 import traceback
@@ -127,14 +121,6 @@ def collective_bytes_from_hlo(hlo: str) -> Dict[str, float]:
             out[m] += float(size)
     out["total"] = sum(v for k, v in out.items() if k != "total")
     return out
-
-
-def _cost_dict(cost):
-    """``Compiled.cost_analysis()`` returns a dict on newer jax and a list
-    of per-device dicts on older jax — normalize to one dict."""
-    if isinstance(cost, (list, tuple)):
-        return cost[0] if cost else {}
-    return cost or {}
 
 
 def _batch_shardings(specs: Dict[str, jax.ShapeDtypeStruct], mesh):
@@ -316,7 +302,7 @@ def run_cell(
                           "output_size_in_bytes")
             ) - report.get("alias_size_in_bytes", 0)
             report["per_device_bytes"] = int(total)
-        cost = _cost_dict(compiled.cost_analysis())
+        cost = compiled.cost_analysis()
         if cost:
             report["hlo_flops_per_device_rolled"] = float(cost.get("flops", -1))
             report["hlo_bytes_per_device_rolled"] = float(
@@ -338,7 +324,7 @@ def run_cell(
             t2 = time.time()
             compiled_u = build(unroll=True).compile()
             report["analysis_compile_s"] = round(time.time() - t2, 2)
-            cost_u = _cost_dict(compiled_u.cost_analysis())
+            cost_u = compiled_u.cost_analysis()
             if cost_u:
                 report["hlo_flops_per_device"] = float(cost_u.get("flops", -1))
                 report["hlo_bytes_per_device"] = float(
@@ -350,7 +336,18 @@ def run_cell(
     return report
 
 
+def use_fake_host_devices(n: int = 512) -> None:
+    """Give the CPU backend ``n`` devices, enough for the production meshes.
+    Call before anything touches a JAX device: the count locks on first
+    use."""
+    os.environ["XLA_FLAGS"] = (
+        f"--xla_force_host_platform_device_count={n} "
+        + os.environ.get("XLA_FLAGS", "")
+    )
+
+
 def main():
+    use_fake_host_devices()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default=None)
     ap.add_argument("--shape", default=None)

@@ -21,13 +21,7 @@ from __future__ import annotations
 from typing import Tuple
 
 import jax
-
-try:  # jax >= 0.5 names explicit/auto axis types; older jax has no kwarg
-    from jax.sharding import AxisType
-
-    _AXIS_KW = lambda n: {"axis_types": (AxisType.Auto,) * n}
-except ImportError:
-    _AXIS_KW = lambda n: {}
+from jax.sharding import AxisType
 
 __all__ = ["make_production_mesh", "make_mesh", "batch_axes"]
 
@@ -35,12 +29,12 @@ __all__ = ["make_production_mesh", "make_mesh", "batch_axes"]
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_AXIS_KW(len(axes)))
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]):
     """Arbitrary mesh with the framework's axis conventions."""
-    return jax.make_mesh(shape, axes, **_AXIS_KW(len(shape)))
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(shape))
 
 
 def batch_axes(mesh) -> tuple:

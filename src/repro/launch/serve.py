@@ -8,13 +8,41 @@ from __future__ import annotations
 
 import argparse
 import time
+from typing import List
 
 import jax
 import numpy as np
 
+from repro.compile_cache import use_compile_cache
 from repro.configs import get_config
 from repro.models import model as M
+from repro.models.config import ArchConfig
 from repro.serve.engine import Request, ServeConfig, ServeEngine
+
+
+def make_requests(cfg: ArchConfig, n: int, max_new: int,
+                  seed: int = 0) -> List[Request]:
+    """``n`` requests with seeded random prompts of 4 to 23 tokens."""
+    rng = np.random.default_rng(seed)
+    reqs = []
+    for i in range(n):
+        length = int(rng.integers(4, 24))
+        reqs.append(Request(
+            rid=i,
+            prompt=rng.integers(0, cfg.vocab, size=length).astype(np.int32),
+            max_new_tokens=max_new,
+        ))
+    return reqs
+
+
+def serve(engine: ServeEngine, requests: List[Request]):
+    """Submit ``requests``, drive the engine until all finish, and return
+    ``(finished requests, wall seconds)``."""
+    t0 = time.perf_counter()
+    for req in requests:
+        engine.submit(req)
+    done = engine.run()
+    return done, time.perf_counter() - t0
 
 
 def main(argv=None):
@@ -27,6 +55,7 @@ def main(argv=None):
     ap.add_argument("--max-new", type=int, default=16)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:
@@ -37,17 +66,8 @@ def main(argv=None):
     params = M.init(cfg, jax.random.PRNGKey(args.seed))
     eng = ServeEngine(cfg, params,
                       ServeConfig(slots=args.slots, max_len=args.max_len))
-    rng = np.random.default_rng(args.seed)
-    t0 = time.time()
-    for i in range(args.requests):
-        n = int(rng.integers(4, 24))
-        eng.submit(Request(
-            rid=i,
-            prompt=rng.integers(0, cfg.vocab, size=n).astype(np.int32),
-            max_new_tokens=args.max_new,
-        ))
-    done = eng.run()
-    dt = time.time() - t0
+    done, dt = serve(eng, make_requests(cfg, args.requests, args.max_new,
+                                        args.seed))
     toks = sum(len(r.output) for r in done)
     print(f"[serve] {len(done)} requests, {toks} tokens, "
           f"{toks/dt:.1f} tok/s, {eng.step_count} decode steps")

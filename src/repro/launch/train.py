@@ -28,6 +28,7 @@ import time
 import jax
 import jax.numpy as jnp
 
+from repro.compile_cache import use_compile_cache
 from repro.configs import get_config, padded_for_tp
 from repro.core.platform import tpu_pod_platform
 from repro.data.pipeline import GeoDataPipeline
@@ -39,6 +40,32 @@ from repro.train.optim import AdamWConfig, cosine_schedule
 from repro.train.train_step import (
     TrainConfig, init_state, make_train_step, state_shardings,
 )
+
+
+def build_training(cfg, tcfg: TrainConfig, mesh=None, lr_fn=None,
+                   seed: int = 0):
+    """The train state's constructor, its shapes and shardings, and the
+    jitted step.  Call inside ``axis_rules(mesh, DEFAULT_RULES)``.
+
+    Returns ``(init, like, shardings, step)``.  ``init()`` makes the state
+    where it lives: each device of ``mesh`` computes only its own shards,
+    so a state larger than one device is never gathered on device 0.
+    ``shardings`` is ``None`` without a mesh."""
+    def make_state():
+        params = M.init(cfg, jax.random.PRNGKey(seed),
+                        tp=mesh.shape["model"] if mesh else 1)
+        return init_state(cfg, params, seed=seed,
+                          compression=tcfg.compression)
+
+    like = jax.eval_shape(make_state)
+    step = make_train_step(cfg, tcfg, mesh=mesh, lr_fn=lr_fn)
+    if mesh is None:
+        return jax.jit(make_state), like, None, jax.jit(step, donate_argnums=(0,))
+    shardings = state_shardings(cfg, like, mesh)
+    init = jax.jit(make_state, out_shardings=shardings)
+    step = jax.jit(step, in_shardings=(shardings, None),
+                   out_shardings=(shardings, None), donate_argnums=(0,))
+    return init, like, shardings, step
 
 
 def main(argv=None):
@@ -66,6 +93,7 @@ def main(argv=None):
     ap.add_argument("--dtype", default="float32",
                     choices=["float32", "bfloat16"])
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:
@@ -106,37 +134,15 @@ def main(argv=None):
     mgr = CheckpointManager(args.ckpt_dir, keep=3) if args.ckpt_dir else None
     start_step = 0
 
-    def build_state():
-        params = M.init(cfg, jax.random.PRNGKey(args.seed),
-                        tp=mesh.shape["model"] if mesh else 1)
-        return init_state(cfg, params, seed=args.seed,
-                          compression=args.compression)
-
     with axis_rules(mesh, DEFAULT_RULES):
-        state = build_state()
+        init, like, shardings, step_fn = build_training(
+            cfg, tcfg, mesh=mesh, lr_fn=lr_fn, seed=args.seed
+        )
         if mgr and args.resume == "auto" and mgr.latest_step() is not None:
-            like = jax.tree.map(
-                lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), state
-            )
-            shard_tree = None
-            if mesh is not None:
-                shard_tree = state_shardings(cfg, like, mesh)
-            state, extras, start_step = mgr.restore(None, like, shard_tree)
+            state, extras, start_step = mgr.restore(None, like, shardings)
             print(f"[resume] restored committed step {start_step}")
-
-        step_fn = make_train_step(cfg, tcfg, mesh=mesh, lr_fn=lr_fn)
-        if mesh is not None:
-            st_sh = state_shardings(
-                cfg,
-                jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
-                             state),
-                mesh,
-            )
-            step_fn = jax.jit(step_fn, in_shardings=(st_sh, None),
-                              out_shardings=(st_sh, None),
-                              donate_argnums=(0,))
         else:
-            step_fn = jax.jit(step_fn, donate_argnums=(0,))
+            state = init()
 
         pipe.start(from_step=start_step)
         t_last = time.time()

@@ -414,8 +414,6 @@ def moe_fwd(
 
     from jax.sharding import PartitionSpec as P
 
-    from ..compat import shard_map
-
     batch_axes = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
 
     def local(xl, router, bias, capf, wg, wu, wd):
@@ -480,7 +478,7 @@ def moe_fwd(
         return y.reshape(Bl, Tl, d), aux
 
     bspec = batch_axes if len(batch_axes) > 1 else (batch_axes[0] if batch_axes else None)
-    yl, aux = shard_map(
+    yl, aux = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(

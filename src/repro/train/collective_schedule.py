@@ -25,8 +25,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from ..compat import shard_map
-
 __all__ = ["hierarchical_allreduce", "flat_size"]
 
 
@@ -76,7 +74,7 @@ def hierarchical_allreduce(
         v = jax.lax.all_gather(v, "data", axis=0, tiled=False).reshape(-1)
         return v / denom
 
-    reduced = shard_map(
+    reduced = jax.shard_map(
         local, mesh=mesh, in_specs=P(), out_specs=P(), check_vma=False
     )(flat)
     reduced = reduced[:n]

@@ -46,33 +46,15 @@ def test_forward_shapes_and_finite(setups, name):
     assert np.isfinite(float(aux))
 
 
-@pytest.mark.parametrize(
-    "name",
-    [
-        pytest.param(
-            n,
-            marks=pytest.mark.xfail(
-                strict=True,
-                reason=(
-                    "llama4-scout is the only top-1 MoE here (reduced() "
-                    "keeps top_k=1): expert assignment is a hard argmax, so "
-                    "the loss is piecewise in the router params and this "
-                    "test's fixed 0.5-LR SGD step crosses an assignment "
-                    "boundary (tokens land on differently-trained experts "
-                    "and the re-evaluated loss rises 6.213→6.230). "
-                    "Deterministic — the same step passes at lr<=0.45 and "
-                    "for every top-k>=2 arch (granite-moe is top-8)."
-                ),
-            ),
-        )
-        if n == "llama4-scout-17b-a16e"
-        else n
-        for n in ARCH_IDS
-    ],
-)
+@pytest.mark.parametrize("name", ARCH_IDS)
 def test_train_step_reduces_loss(setups, name):
-    """One SGD step on a fixed batch must not produce NaNs and must reduce
-    the loss on that same batch (sanity of the whole grad path)."""
+    """A few SGD steps on a fixed batch must not produce NaNs and must
+    reduce the loss on that same batch (sanity of the whole grad path).
+
+    The step is small and the check spans several steps because top-1 MoE
+    routing (llama4-scout) makes the loss piecewise in the router params:
+    one large step can cross an expert-assignment boundary and raise the
+    re-evaluated loss, which says nothing about the gradient."""
     cfg, params = setups[name]
     batch = _batch(cfg, jax.random.PRNGKey(2))
 
@@ -81,16 +63,18 @@ def test_train_step_reduces_loss(setups, name):
         (l, metrics), g = jax.value_and_grad(
             lambda p_: M.loss_fn(cfg, p_, batch), has_aux=True
         )(p)
-        p2 = jax.tree.map(lambda a, b: a - 0.5 * b, p, g)
+        p2 = jax.tree.map(lambda a, b: a - 0.1 * b, p, g)
         return l, p2
 
-    l0, p1 = step(params)
-    l1, _ = step(p1)
-    assert np.isfinite(float(l0)) and np.isfinite(float(l1))
-    assert float(l1) < float(l0), (name, float(l0), float(l1))
+    losses, p = [], params
+    for _ in range(4):
+        l, p = step(p)
+        losses.append(float(l))
+    assert np.isfinite(losses).all(), (name, losses)
+    assert losses[-1] < losses[0], (name, losses)
     # gradients flowed into every parameter group
     flat = jax.tree_util.tree_leaves(
-        jax.tree.map(lambda a, b: float(jnp.abs(a - b).sum()), params, p1)
+        jax.tree.map(lambda a, b: float(jnp.abs(a - b).sum()), params, p)
     )
     assert sum(1 for v in flat if v > 0) > len(flat) * 0.5
 

@@ -167,6 +167,17 @@ class TestSegmentSum:
             np.asarray(out), np.asarray(ref.segment_sum_ref(values, ids, 7)), atol=1e-4
         )
 
+    def test_many_segments_shrink_the_row_block_and_stay_exact(self):
+        """5,000 segments shrink the row block to fit the one-hot budget;
+        integer counts up to 2^20 still sum exactly (fp32 contraction)."""
+        rng = np.random.default_rng(1)
+        n, s = 700, 5000
+        ids = np.sort(rng.integers(0, s, size=n)).astype(np.int32)
+        counts = rng.integers(1, 1 << 20, size=n).astype(np.float32)
+        out = segment_sum(jnp.asarray(counts[:, None]), jnp.asarray(ids), s)
+        expect = np.bincount(ids, weights=counts.astype(np.int64), minlength=s)
+        np.testing.assert_array_equal(np.asarray(out)[:, 0], expect)
+
 
 class TestMoEDispatch:
     @pytest.mark.parametrize("T,D,E,C", [(128, 32, 4, 40), (200, 64, 8, 16), (64, 16, 3, 64)])
@@ -214,3 +225,24 @@ class TestOpsFallback:
             np.asarray(ref.attention_ref(q, k, v)),
             atol=1e-6,
         )
+
+    def test_segment_sum_path_names_what_runs(self):
+        assert ops.segment_sum_path(ops._MIN_KERNEL_SEQ - 1) == "reference"
+        assert ops.segment_sum_path(4096, use_kernel=False) == "reference"
+        assert ops.segment_sum_path(4096) == "pallas-interpret"  # CPU suite
+
+
+def test_interpret_mode_only_on_cpu(monkeypatch):
+    """Kernels compile on TPU, interpret on CPU, and refuse anything else
+    rather than silently interpreting on an accelerator."""
+    from repro.kernels import interpret_mode
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert interpret_mode() is False
+    monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
+    assert interpret_mode() is True
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="gpu"):
+        interpret_mode()
+    with pytest.raises(RuntimeError, match="gpu"):
+        segment_sum(jnp.ones((8, 1)), jnp.zeros(8, jnp.int32), 2)

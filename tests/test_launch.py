@@ -12,7 +12,6 @@ _ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 class TestCollectiveParser:
     def _parse(self, hlo):
-        # import without triggering the 512-device flag side effect
         import repro.launch.dryrun as dr
 
         return dr.collective_bytes_from_hlo(hlo)
@@ -112,6 +111,7 @@ class TestTrainLauncherResume:
         loss trajectory must continue (fault-tolerance deliverable)."""
         env = dict(os.environ)
         env["PYTHONPATH"] = os.path.join(_ROOT, "src")
+        env["JAX_PLATFORMS"] = "cpu"  # a child never takes the chip
         base = [
             sys.executable, "-m", "repro.launch.train",
             "--arch", "qwen3-1.7b", "--reduced",

@@ -28,6 +28,7 @@ def run_sub(body: str, n_devices: int = 8) -> str:
     )
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(_ROOT, "src")
+    env["JAX_PLATFORMS"] = "cpu"  # a child never takes the chip
     env.pop("XLA_FLAGS", None)
     r = subprocess.run(
         [sys.executable, "-c", code], capture_output=True, text=True,
