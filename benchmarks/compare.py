@@ -31,7 +31,7 @@ toolchain-driven mismatch is distinguishable from a code regression.
 
 Refreshing after an intentional change::
 
-    PYTHONPATH=src python -m benchmarks.run --quick --skip-roofline \
+    PYTHONPATH=src python -m benchmarks.run --quick \
         --json reports/bench-timings.json
     PYTHONPATH=src python -m benchmarks.compare reports/bench-timings.json \
         --update-baseline

@@ -1,5 +1,5 @@
-"""Benchmark driver — one benchmark per paper figure plus the roofline
-table, all driven through the :class:`repro.api.GeoJob` facade (plan →
+"""Benchmark runner — one benchmark per paper figure, all driven
+through the :class:`repro.api.GeoJob` facade (plan →
 price → execute on one shared cost model).  Emits
 ``name,us_per_call,derived`` CSV rows (also saved to
 ``reports/benchmarks.csv``) and a JSON dump of full results.
@@ -9,8 +9,7 @@ price → execute on one shared cost model).  Emits
 scenario produces them — which CI uploads as an artifact to seed the bench
 trajectory.
 
-    PYTHONPATH=src python -m benchmarks.run [--skip-roofline] [--quick]
-                                            [--json PATH]
+    PYTHONPATH=src python -m benchmarks.run [--quick] [--json PATH]
                                             [--planner-json PATH]
 
 The JSON meta header records jax/numpy/git provenance plus the solver
@@ -58,8 +57,6 @@ def _provenance() -> dict:
 
 def main() -> None:
     ap = argparse.ArgumentParser()
-    ap.add_argument("--skip-roofline", action="store_true",
-                    help="skip the dry-run-report-based roofline table")
     ap.add_argument("--quick", action="store_true",
                     help="small solver budgets (smoke-run the whole suite)")
     ap.add_argument("--json", default=None, metavar="PATH",
@@ -144,17 +141,6 @@ def main() -> None:
         with open(profile_path, "w") as f:
             f.write(buf.getvalue())
         print(f"[profile] top-20 cumulative in {profile_path}")
-
-    if not args.skip_roofline and os.path.isdir(
-        os.path.join(args.out, "dryrun")
-    ):
-        from . import roofline
-
-        t0 = time.perf_counter()
-        rows = roofline.run(os.path.join(args.out, "dryrun"),
-                            os.path.join(args.out, "roofline.md"))
-        results["roofline"] = rows
-        wall["roofline"] = time.perf_counter() - t0
 
     flush_csv(os.path.join(args.out, "benchmarks.csv"))
 

@@ -58,6 +58,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from . import tracing
 from .analysis.validate import validate_plan_shapes
 from .core.makespan import BARRIERS_GGL, CostModel, attribute_phases
 from .core.optimize import (
@@ -269,23 +270,26 @@ class GeoJob:
     def execute(self, per_source: Sequence[Records]) -> JobReport:
         """Run the application under the planned execution plan, price the
         measured byte movement through the same cost model the planner used,
-        and report modeled-vs-measured timings."""
+        and report modeled-vs-measured timings; the job is the span
+        ``geoplan.execute``."""
         if self.app is None:
             raise RuntimeError(
                 "execute() needs an application — construct GeoJob(platform, app) "
                 "or use .simulate() for a model-only run"
             )
         result = self.planned
-        engine = GeoMapReduce(
-            self.platform, result.plan, self.app, n_buckets=self.n_buckets
-        )
-        outputs, stats = engine.run(per_source)
-        cm = CostModel(self.platform, result.barriers)
+        with tracing.span("geoplan.execute"):
+            engine = GeoMapReduce(
+                self.platform, result.plan, self.app, n_buckets=self.n_buckets
+            )
+            outputs, stats = engine.run(per_source)
+            cm = CostModel(self.platform, result.barriers)
+            measured = cm.breakdown_volumes(*stats.volumes_mb())
         return JobReport(
             result=result,
             stats=stats,
             modeled=result.breakdown,
-            measured=cm.breakdown_volumes(*stats.volumes_mb()),
+            measured=measured,
             outputs=outputs,
             barriers=result.barriers,
         )

@@ -48,6 +48,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import tracing
 from .makespan import (
     BARRIERS_ALL_GLOBAL,
     CostModel,
@@ -250,12 +251,13 @@ def _adam_anneal(loss, params0, steps: int, scale, lr, tau0_frac, tau1_frac):
 # counters
 # ---------------------------------------------------------------------------
 
-#: cumulative counters over every compiled-solver call in this process;
-#: read with :func:`solver_cache_stats`, zero with
+#: cumulative counters over every compiled-solver call in this process,
+#: kept as the ``solver.<name>`` counters of :mod:`repro.tracing`; read
+#: with :func:`solver_cache_stats`, zero with
 #: :func:`reset_solver_cache_stats`.  The executable cache itself is
 #: jit's own (module-level, so it survives across GeoSchedule /
 #: SolverService instances); these counters make it observable.
-_SOLVER_STATS = {"calls": 0, "hits": 0, "misses": 0, "compiles": 0}
+_SOLVER_COUNTERS = ("calls", "hits", "misses", "compiles")
 _SOLVER_KEYS: set = set()
 
 
@@ -297,16 +299,16 @@ def _counted_solver(static_argnames: Tuple[str, ...] = ()):
                         _abstract_leaf(leaf) for leaf in jax.tree.leaves(val)
                     )))
             key = tuple(parts)
-            _SOLVER_STATS["calls"] += 1
+            tracing.count("solver.calls")
             if key in _SOLVER_KEYS:
-                _SOLVER_STATS["hits"] += 1
+                tracing.count("solver.hits")
             else:
-                _SOLVER_STATS["misses"] += 1
+                tracing.count("solver.misses")
                 _SOLVER_KEYS.add(key)
             before = jitted._cache_size()
             out = jitted(*args, **kwargs)
             if jitted._cache_size() > before:
-                _SOLVER_STATS["compiles"] += 1
+                tracing.count("solver.compiles")
             return out
 
         wrapper._jitted = jitted
@@ -369,15 +371,15 @@ def solver_cache_stats() -> Dict[str, int]:
     (distinct keys alive) and ``shapes`` (distinct kernel+shape groups —
     see :func:`solver_cache_occupancy` for the full breakdown)."""
     labels = {_occupancy_label(key) for key in _SOLVER_KEYS}
-    return dict(_SOLVER_STATS, entries=len(_SOLVER_KEYS),
-                shapes=len(labels))
+    counts = tracing.counters()
+    return dict({k: counts.get(f"solver.{k}", 0) for k in _SOLVER_COUNTERS},
+                entries=len(_SOLVER_KEYS), shapes=len(labels))
 
 
 def reset_solver_cache_stats() -> None:
     """Zero the counters (the compiled executables themselves stay
     cached — this resets accounting, not the cache)."""
-    for k in _SOLVER_STATS:
-        _SOLVER_STATS[k] = 0
+    tracing.reset(*(f"solver.{k}" for k in _SOLVER_COUNTERS))
     _SOLVER_KEYS.clear()
 
 
@@ -434,6 +436,18 @@ def _solve_batch_many(
     )
 
 
+def _to_device(*arrays, dtype=None):
+    """Host-to-device puts, each counted as a ``plan.h2d`` transfer."""
+    tracing.count("plan.h2d", len(arrays))
+    return tuple(jnp.asarray(a, dtype) for a in arrays)
+
+
+def _to_host(x) -> np.ndarray:
+    """A device-to-host fetch, counted as a ``plan.d2h`` transfer."""
+    tracing.count("plan.d2h")
+    return np.asarray(x)
+
+
 def _initial_logits(platform: Platform, n_restarts: int, seed: int):
     """Random inits plus deterministic warm starts (uniform, local push,
     bandwidth-greedy)."""
@@ -459,7 +473,7 @@ def _initial_logits(platform: Platform, n_restarts: int, seed: int):
         ly.append(rng.normal(0.0, sigma, size=(nR,)))
     lx = np.stack(lx[:n_restarts]).astype(np.float32)
     ly = np.stack(ly[:n_restarts]).astype(np.float32)
-    return jnp.asarray(lx), jnp.asarray(ly)
+    return _to_device(lx, ly)
 
 
 def _run_solver_many(
@@ -480,53 +494,53 @@ def _run_solver_many(
     see :func:`optimize_plan_batch`); per-request ``D``/``alpha``/
     capacities/seeds are free.  Returns one ``(x, y, exact)`` per request,
     the best restart under the exact hard-max model, float64-renormalized.
+
+    Spans ``geoplan.plan.prep`` (host inputs and their puts), ``.solve``
+    (the dispatch through the fetch of the objectives) and ``.fetch`` (the
+    best restarts' plans back to the host) split the call.
     """
     B = len(platforms)
-    raw = [p.as_arrays() for p in platforms]
-    arrs = tuple(
-        jnp.asarray(np.stack([np.asarray(r[i], dtype=np.float64)
-                              for r in raw]), jnp.float32)
-        for i in range(6)
-    )
-    if x_fixed_list is None:
-        x_fixed_list = [None] * B
-    if y_fixed_list is None:
-        y_fixed_list = [None] * B
-    xf = np.stack([
-        uniform_plan(p).x if x is None else np.asarray(x)
-        for p, x in zip(platforms, x_fixed_list)
-    ])
-    yf = np.stack([
-        uniform_plan(p).y if y is None else np.asarray(y)
-        for p, y in zip(platforms, y_fixed_list)
-    ])
-    scales = np.array([
-        max(makespan(p, uniform_plan(p), barriers=barriers), 1e-6)
-        for p in platforms
-    ])
-    inits = [_initial_logits(p, n_restarts, s)
-             for p, s in zip(platforms, seeds)]
-    xs, ys, exact = _solve_batch_many(
-        arrs,
-        jnp.stack([lx for lx, _ in inits]),
-        jnp.stack([ly for _, ly in inits]),
-        jnp.asarray(xf, jnp.float32),
-        jnp.asarray(yf, jnp.float32),
-        jnp.asarray(scales, jnp.float32),
-        loss_kind,
-        tuple(barriers),
-        opt_x,
-        opt_y,
-        steps,
-    )
-    exact = np.asarray(exact)
+    with tracing.span("geoplan.plan.prep"):
+        raw = [p.as_arrays() for p in platforms]
+        arrs = _to_device(*(
+            np.stack([np.asarray(r[i], dtype=np.float64) for r in raw])
+            for i in range(6)
+        ), dtype=jnp.float32)
+        if x_fixed_list is None:
+            x_fixed_list = [None] * B
+        if y_fixed_list is None:
+            y_fixed_list = [None] * B
+        xf = np.stack([
+            uniform_plan(p).x if x is None else np.asarray(x)
+            for p, x in zip(platforms, x_fixed_list)
+        ])
+        yf = np.stack([
+            uniform_plan(p).y if y is None else np.asarray(y)
+            for p, y in zip(platforms, y_fixed_list)
+        ])
+        scales = np.array([
+            max(makespan(p, uniform_plan(p), barriers=barriers), 1e-6)
+            for p in platforms
+        ])
+        inits = [_initial_logits(p, n_restarts, s)
+                 for p, s in zip(platforms, seeds)]
+        lx0 = jnp.stack([lx for lx, _ in inits])
+        ly0 = jnp.stack([ly for _, ly in inits])
+        xf, yf, scales = _to_device(xf, yf, scales, dtype=jnp.float32)
+    with tracing.span("geoplan.plan.solve"):
+        xs, ys, exact = _solve_batch_many(
+            arrs, lx0, ly0, xf, yf, scales, loss_kind, tuple(barriers),
+            opt_x, opt_y, steps,
+        )
+        exact = _to_host(exact)
     out = []
-    for b in range(B):
-        best = int(np.argmin(exact[b]))
-        # renormalize against float32 round-off so the plan validates
-        plan = ExecutionPlan.renormalized(np.asarray(xs[b, best]),
-                                          np.asarray(ys[b, best]))
-        out.append((plan.x, plan.y, float(exact[b, best])))
+    with tracing.span("geoplan.plan.fetch"):
+        for b in range(B):
+            best = int(np.argmin(exact[b]))
+            # renormalize against float32 round-off so the plan validates
+            plan = ExecutionPlan.renormalized(_to_host(xs[b, best]),
+                                              _to_host(ys[b, best]))
+            out.append((plan.x, plan.y, float(exact[b, best])))
     return out
 
 
@@ -734,6 +748,10 @@ def optimize_plan_batch(
     push matrix per request for the shuffle-only modes.  Results are
     per-request :class:`PlanResult`\\ s, identical (to float32 vmap
     round-off) to calling :func:`optimize_plan` per request.
+
+    Span ``geoplan.plan_many`` covers the call, with attrs ``requests``
+    and the transfers it made each way (``plan.h2d``, ``plan.d2h``);
+    ``geoplan.plan.price`` covers a group's float64 pricing.
     """
     platforms = list(platforms)
     barriers = tuple(barriers)
@@ -758,20 +776,24 @@ def optimize_plan_batch(
     for g, p in enumerate(platforms):
         groups.setdefault((p.nS, p.nM, p.nR), []).append(g)
     results: "list[Optional[PlanResult]]" = [None] * len(platforms)
-    for idxs in groups.values():
-        planned = _plan_group(
-            [platforms[g] for g in idxs], mode, barriers, n_restarts,
-            steps, [seeds[g] for g in idxs], [fixed_x[g] for g in idxs],
-        )
-        for g, (plan, obj) in zip(idxs, planned):
-            results[g] = PlanResult(
-                plan=plan,
-                makespan=makespan(platforms[g], plan, barriers),
-                breakdown=phase_breakdown(platforms[g], plan, barriers),
-                mode=mode,
-                barriers=barriers,
-                objective=float(obj),
+    with tracing.span("geoplan.plan_many", ("plan.h2d", "plan.d2h"),
+                      requests=len(platforms)):
+        for idxs in groups.values():
+            planned = _plan_group(
+                [platforms[g] for g in idxs], mode, barriers, n_restarts,
+                steps, [seeds[g] for g in idxs], [fixed_x[g] for g in idxs],
             )
+            with tracing.span("geoplan.plan.price"):
+                for g, (plan, obj) in zip(idxs, planned):
+                    results[g] = PlanResult(
+                        plan=plan,
+                        makespan=makespan(platforms[g], plan, barriers),
+                        breakdown=phase_breakdown(platforms[g], plan,
+                                                  barriers),
+                        mode=mode,
+                        barriers=barriers,
+                        objective=float(obj),
+                    )
     return results  # type: ignore[return-value]
 
 

@@ -10,8 +10,8 @@ For each cell this script
   4. ``lower().compile()`` — a sharding mismatch, compile-time OOM or
      unsupported collective here is a bug in the framework,
   5. records ``memory_analysis()`` / ``cost_analysis()`` and the collective
-     traffic parsed from the partitioned HLO into a JSON report that the
-     roofline analysis (benchmarks/roofline.py, EXPERIMENTS.md) consumes.
+     traffic parsed from the partitioned HLO into a JSON report (compile-
+     time counts on described devices, not a measurement on a chip).
 
 Usage::
 
@@ -318,8 +318,8 @@ def run_cell(
         # XLA's cost_analysis counts while-loop bodies ONCE (verified in
         # EXPERIMENTS.md §Dry-run); unrolling the layer-group scan makes
         # FLOPs/bytes/collectives per-layer-correct.  The chunked-attention
-        # inner scans remain rolled; their matmul FLOPs are added
-        # analytically by benchmarks/roofline.py.
+        # inner scans remain rolled, so their matmul FLOPs are counted
+        # once per scan, not per iteration.
         if analysis:
             t2 = time.time()
             compiled_u = build(unroll=True).compile()
@@ -378,8 +378,8 @@ def main():
                 continue
             print(f"[dryrun] {tag} ...", flush=True)
             try:
-                # the unrolled analysis build feeds the (single-pod-only)
-                # roofline table; multi-pod cells prove sharding + memory.
+                # the unrolled analysis build gives per-layer counts
+                # (single pod only); multi-pod cells prove sharding + memory.
                 rep = run_cell(arch, shape, mp, analysis=not mp)
                 with open(path, "w") as f:
                     json.dump(rep, f, indent=1)

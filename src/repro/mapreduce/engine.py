@@ -30,6 +30,7 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from .. import tracing
 from ..core.makespan import CostModel
 from ..core.plan import ExecutionPlan
 from ..core.platform import Platform
@@ -196,11 +197,17 @@ class GeoMapReduce:
 
     # -- run ----------------------------------------------------------------
     def run(self, per_source: Sequence[Records]):
-        """Execute; returns (per-reducer outputs, PhaseStats)."""
-        per_mapper, push_bytes = self._push(per_source)
-        mapped, map_in = self._map(per_mapper)
-        per_reducer, shuffle_bytes = self._shuffle(mapped)
-        outs, reduce_in = self._reduce(per_reducer)
+        """Execute; returns (per-reducer outputs, PhaseStats).  Each phase
+        is a span: ``geoplan.engine.push``, ``.map``, ``.shuffle`` and
+        ``.reduce``."""
+        with tracing.span("geoplan.engine.push"):
+            per_mapper, push_bytes = self._push(per_source)
+        with tracing.span("geoplan.engine.map"):
+            mapped, map_in = self._map(per_mapper)
+        with tracing.span("geoplan.engine.shuffle"):
+            per_reducer, shuffle_bytes = self._shuffle(mapped)
+        with tracing.span("geoplan.engine.reduce"):
+            outs, reduce_in = self._reduce(per_reducer)
         total_in = max(map_in.sum(), 1e-9)
         stats = PhaseStats(
             push_bytes=push_bytes,
