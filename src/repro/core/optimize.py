@@ -383,10 +383,7 @@ def reset_solver_cache_stats() -> None:
     _SOLVER_KEYS.clear()
 
 
-@_counted_solver(
-    static_argnames=("loss_kind", "barriers", "opt_x", "opt_y", "steps")
-)
-def _solve_batch_many(
+def _anneal_restarts(
     arrs,  # 6-tuple of (B, ...) arrays: D, B_sm, B_mr, C_m, C_r, alpha
     logits_x0,  # (B, R, nS, nM)
     logits_y0,  # (B, R, nR)
@@ -402,10 +399,10 @@ def _solve_batch_many(
     tau0_frac: float = 0.3,
     tau1_frac: float = 1e-3,
 ):
-    """Run ``B`` independent solve requests × ``R`` Adam restarts of
-    ``steps`` annealed iterations in **one** compiled dispatch (requests
-    vmapped over restarts vmapped over the anneal); return per-request,
-    per-restart final (x, y) plus their exact hard-model objectives."""
+    """``B`` independent solve requests × ``R`` Adam restarts of ``steps``
+    annealed iterations (requests vmapped over restarts vmapped over the
+    anneal); per-request, per-restart final (x, y) plus their exact
+    hard-model objectives.  Traced inside :func:`_solve_batch_many`."""
     loss_core = _objective_fn(loss_kind, barriers)
 
     def one_request(arrs_b, lx_b, ly_b, xf, yf, sc):
@@ -436,21 +433,50 @@ def _solve_batch_many(
     )
 
 
-def _to_device(*arrays, dtype=None):
-    """Host-to-device puts, each counted as a ``plan.h2d`` transfer."""
-    tracing.count("plan.h2d", len(arrays))
-    return tuple(jnp.asarray(a, dtype) for a in arrays)
+@_counted_solver(
+    static_argnames=("loss_kind", "barriers", "opt_x", "opt_y", "steps")
+)
+def _solve_batch_many(
+    arrs,
+    logits_x0,
+    logits_y0,
+    x_fixed,
+    y_fixed,
+    scale,
+    loss_kind: str,
+    barriers: Tuple[str, str, str],
+    opt_x: bool,
+    opt_y: bool,
+    steps: int,
+    lr: float = 0.08,
+    tau0_frac: float = 0.3,
+    tau1_frac: float = 1e-3,
+):
+    """Run :func:`_anneal_restarts` in **one** compiled dispatch and pick
+    each request's best restart under the exact hard-max model on the
+    device (the first minimum, as ``np.argmin``): per request the best
+    ``x`` ``(B, nS, nM)``, ``y`` ``(B, nR)`` and its objective ``(B,)``,
+    so only one plan per request comes back to the host."""
+    xs, ys, exact = _anneal_restarts(
+        arrs, logits_x0, logits_y0, x_fixed, y_fixed, scale, loss_kind,
+        barriers, opt_x, opt_y, steps, lr, tau0_frac, tau1_frac,
+    )
+    best = jnp.argmin(exact, axis=1)
+    hit = jnp.arange(exact.shape[1])[None, :] == best[:, None]
 
+    # A masked max over the restarts is exact.  A gather
+    # (``take_along_axis``) made the TPU compiler lay out and fuse the
+    # final softmax differently, so plans moved in their last float32 bit.
+    def pick(a):
+        m = hit.reshape(hit.shape + (1,) * (a.ndim - 2))
+        return jnp.max(jnp.where(m, a, -jnp.inf), axis=1)
 
-def _to_host(x) -> np.ndarray:
-    """A device-to-host fetch, counted as a ``plan.d2h`` transfer."""
-    tracing.count("plan.d2h")
-    return np.asarray(x)
+    return pick(xs), pick(ys), pick(exact)
 
 
 def _initial_logits(platform: Platform, n_restarts: int, seed: int):
     """Random inits plus deterministic warm starts (uniform, local push,
-    bandwidth-greedy)."""
+    bandwidth-greedy), as float32 host arrays ``(R, nS, nM)``, ``(R, nR)``."""
     rng = np.random.default_rng(seed)
     nS, nM, nR = platform.nS, platform.nM, platform.nR
     eps = 1e-9
@@ -473,7 +499,7 @@ def _initial_logits(platform: Platform, n_restarts: int, seed: int):
         ly.append(rng.normal(0.0, sigma, size=(nR,)))
     lx = np.stack(lx[:n_restarts]).astype(np.float32)
     ly = np.stack(ly[:n_restarts]).astype(np.float32)
-    return _to_device(lx, ly)
+    return lx, ly
 
 
 def _run_solver_many(
@@ -495,17 +521,19 @@ def _run_solver_many(
     capacities/seeds are free.  Returns one ``(x, y, exact)`` per request,
     the best restart under the exact hard-max model, float64-renormalized.
 
-    Spans ``geoplan.plan.prep`` (host inputs and their puts), ``.solve``
-    (the dispatch through the fetch of the objectives) and ``.fetch`` (the
-    best restarts' plans back to the host) split the call.
+    One host-to-device put of the 11 float32 inputs and one fetch of the 3
+    outputs, whatever ``B`` (counted as ``plan.h2d`` and ``plan.d2h``
+    arrays).  Spans ``geoplan.plan.prep`` (host inputs and the put),
+    ``.solve`` (the dispatch through the device's finish) and ``.fetch``
+    (the fetch and the renormalization) split the call.
     """
     B = len(platforms)
     with tracing.span("geoplan.plan.prep"):
         raw = [p.as_arrays() for p in platforms]
-        arrs = _to_device(*(
+        arrs = tuple(
             np.stack([np.asarray(r[i], dtype=np.float64) for r in raw])
             for i in range(6)
-        ), dtype=jnp.float32)
+        )
         if x_fixed_list is None:
             x_fixed_list = [None] * B
         if y_fixed_list is None:
@@ -524,23 +552,26 @@ def _run_solver_many(
         ])
         inits = [_initial_logits(p, n_restarts, s)
                  for p, s in zip(platforms, seeds)]
-        lx0 = jnp.stack([lx for lx, _ in inits])
-        ly0 = jnp.stack([ly for _, ly in inits])
-        xf, yf, scales = _to_device(xf, yf, scales, dtype=jnp.float32)
+        lx0 = np.stack([lx for lx, _ in inits])
+        ly0 = np.stack([ly for _, ly in inits])
+        inputs = tuple(np.asarray(a, np.float32)
+                       for a in (*arrs, lx0, ly0, xf, yf, scales))
+        tracing.count("plan.h2d", len(inputs))
+        inputs = jax.device_put(inputs)
     with tracing.span("geoplan.plan.solve"):
-        xs, ys, exact = _solve_batch_many(
-            arrs, lx0, ly0, xf, yf, scales, loss_kind, tuple(barriers),
-            opt_x, opt_y, steps,
+        best = _solve_batch_many(
+            inputs[:6], *inputs[6:], loss_kind, tuple(barriers), opt_x,
+            opt_y, steps,
         )
-        exact = _to_host(exact)
-    out = []
+        jax.block_until_ready(best)
     with tracing.span("geoplan.plan.fetch"):
+        tracing.count("plan.d2h", len(best))
+        xs, ys, exact = jax.device_get(best)
+        out = []
         for b in range(B):
-            best = int(np.argmin(exact[b]))
             # renormalize against float32 round-off so the plan validates
-            plan = ExecutionPlan.renormalized(_to_host(xs[b, best]),
-                                              _to_host(ys[b, best]))
-            out.append((plan.x, plan.y, float(exact[b, best])))
+            plan = ExecutionPlan.renormalized(xs[b], ys[b])
+            out.append((plan.x, plan.y, float(exact[b])))
     return out
 
 
@@ -1409,8 +1440,8 @@ def _replan_logits(platform, incumbent, n_restarts, seed, incremental):
         return (np.stack(lx[:n_restarts]).astype(np.float32),
                 np.stack(ly[:n_restarts]).astype(np.float32))
     lx0, ly0 = _initial_logits(platform, max(n_restarts - 1, 1), seed)
-    lx = np.concatenate([lx_inc[None], np.asarray(lx0)])[:n_restarts]
-    ly = np.concatenate([ly_inc[None], np.asarray(ly0)])[:n_restarts]
+    lx = np.concatenate([lx_inc[None], lx0])[:n_restarts]
+    ly = np.concatenate([ly_inc[None], ly0])[:n_restarts]
     return lx.astype(np.float32), ly.astype(np.float32)
 
 #: low-temperature anneal for incremental re-solves: the tau schedule

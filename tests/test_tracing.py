@@ -13,7 +13,7 @@ import pytest
 from repro import tracing
 from repro.api import GeoJob
 from repro.core import SolverService
-from repro.core.platform import planetlab_platform
+from repro.core.platform import planetlab_platform, two_cluster_example
 from repro.mapreduce.apps import word_count
 
 STAGES = ("geoplan.plan.prep", "geoplan.plan.solve", "geoplan.plan.fetch",
@@ -70,7 +70,7 @@ def test_nothing_is_recorded_outside_a_session(svc, ring):
     assert tracing.spans() == []
 
 
-@pytest.mark.parametrize("b", [1, 4])
+@pytest.mark.parametrize("b", [1, 4, 8])
 def test_one_root_per_call_with_its_stages_and_transfers(svc, ring, b):
     _plan(svc, b)  # compiles
     tracing.clear()
@@ -86,11 +86,24 @@ def test_one_root_per_call_with_its_stages_and_transfers(svc, ring, b):
         assert k.root_id == root.span_id
         assert root.start_ns <= k.start_ns <= k.end_ns <= root.end_ns
     assert all(a.end_ns <= z.start_ns for a, z in zip(kids, kids[1:]))
-    assert root.attrs == {"requests": b, "plan.h2d": 9 + 2 * b,
-                          "plan.d2h": 1 + 2 * b}
-    assert after["plan.h2d"] - before["plan.h2d"] == 9 + 2 * b
-    assert after["plan.d2h"] - before["plan.d2h"] == 1 + 2 * b
+    assert root.attrs == {"requests": b, "plan.h2d": 11, "plan.d2h": 3}
+    assert after["plan.h2d"] - before["plan.h2d"] == 11
+    assert after["plan.d2h"] - before["plan.d2h"] == 3
     assert len(tracing.spans()) == 5
+
+
+def test_each_shape_group_makes_one_put_and_one_fetch(svc, ring):
+    platforms = [planetlab_platform(1, alpha=1.0, seed=s) for s in range(2)]
+    platforms += [two_cluster_example(alpha=a) for a in (0.5, 1.0, 2.0)]
+    assert len({(p.nS, p.nM, p.nR) for p in platforms}) == 2
+    svc.plan_many(platforms, seeds=list(range(5)))  # compiles
+    tracing.clear()
+    with tracing.recording():
+        assert len(svc.plan_many(platforms, seeds=list(range(5)))) == 5
+    (root, kids), = _calls("geoplan.plan_many")
+    assert [k.name for k in kids] == list(STAGES) * 2
+    assert root.attrs == {"requests": 5, "plan.h2d": 2 * 11,
+                          "plan.d2h": 2 * 3}
 
 
 def test_the_ring_keeps_the_newest_spans(ring):
