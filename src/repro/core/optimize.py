@@ -42,6 +42,7 @@ import dataclasses
 import functools
 import inspect
 import itertools
+import math
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -417,10 +418,11 @@ def _anneal_restarts(
             return loss_core(arrs_b, x, y, mx, pmax) / sc
 
         def one_restart(lx0, ly0):
-            params = _adam_anneal(
-                loss, {"x": lx0, "y": ly0}, steps, sc, lr, tau0_frac,
-                tau1_frac,
-            )
+            params = {"x": lx0, "y": ly0}
+            if steps:  # no scan (and no gradient) to trace for 0 steps
+                params = _adam_anneal(
+                    loss, params, steps, sc, lr, tau0_frac, tau1_frac,
+                )
             x, y = build(params)
             mx, pmax = hard_ops()
             exact = loss_core(arrs_b, x, y, mx, pmax)
@@ -434,7 +436,8 @@ def _anneal_restarts(
 
 
 @_counted_solver(
-    static_argnames=("loss_kind", "barriers", "opt_x", "opt_y", "steps")
+    static_argnames=("loss_kind", "barriers", "opt_x", "opt_y", "steps",
+                     "tau0_frac")
 )
 def _solve_batch_many(
     arrs,
@@ -456,11 +459,34 @@ def _solve_batch_many(
     each request's best restart under the exact hard-max model on the
     device (the first minimum, as ``np.argmin``): per request the best
     ``x`` ``(B, nS, nM)``, ``y`` ``(B, nR)`` and its objective ``(B,)``,
-    so only one plan per request comes back to the host."""
-    xs, ys, exact = _anneal_restarts(
+    so only one plan per request comes back to the host.
+
+    The un-annealed warm starts (the first :data:`_WARM_STARTS` restarts'
+    initial plans) are candidates too, after the annealed restarts: an
+    anneal that fails cannot return a plan priced above the plans it began
+    from.  ``tau0_frac`` is static: :func:`_anneal_tau0_frac` sets it from
+    the shapes, which key the executable already."""
+    annealed = _anneal_restarts(
         arrs, logits_x0, logits_y0, x_fixed, y_fixed, scale, loss_kind,
         barriers, opt_x, opt_y, steps, lr, tau0_frac, tau1_frac,
     )
+    w = min(_WARM_STARTS, logits_x0.shape[1])
+    warm = _anneal_restarts(
+        arrs, logits_x0[:, :w], logits_y0[:, :w], x_fixed, y_fixed, scale,
+        loss_kind, barriers, opt_x, opt_y, 0,
+    )
+    x, y, exact = _best_restart(*annealed)
+    xw, yw, exact_w = _best_restart(*warm)
+    # a warm start only where it is strictly better, or the anneal gave NaN
+    use_w = ~(exact <= exact_w)
+    return (jnp.where(use_w[:, None, None], xw, x),
+            jnp.where(use_w[:, None], yw, y),
+            jnp.where(use_w, exact_w, exact))
+
+
+def _best_restart(xs, ys, exact):
+    """Per request, the restart with the least ``exact`` (the first
+    minimum, as ``np.argmin``): its ``x``, ``y`` and ``exact``."""
     best = jnp.argmin(exact, axis=1)
     hit = jnp.arange(exact.shape[1])[None, :] == best[:, None]
 
@@ -472,6 +498,37 @@ def _solve_batch_many(
         return jnp.max(jnp.where(m, a, -jnp.inf), axis=1)
 
     return pick(xs), pick(ys), pick(exact)
+
+
+#: the warm starts :func:`_initial_logits` puts first: uniform, locality
+#: and bandwidth-greedy
+_WARM_STARTS = 3
+
+#: terms of the planetlab8 testbed's widest smooth max (8 x 8 push links);
+#: up to here the fresh-plan anneal starts at ``tau0_frac`` 0.3
+_TAU_TERMS_REF = 64
+#: the coldest start: where ``tau0_frac · ln n`` is this share of the
+#: uniform plan's makespan
+_TAU_SMOOTHING_FLOOR = 0.05
+
+
+def _anneal_tau0_frac(nS: int, nM: int, nR: int) -> float:
+    """The fresh-plan anneal's starting temperature, as a share of the
+    uniform plan's makespan, for the shape ``(nS, nM, nR)``.
+
+    ``tau·logsumexp(v/tau)`` over ``n`` terms overstates the hard max by
+    up to ``tau·ln n``; over hundreds of thousands of links a warm smooth
+    max rewards spreading load over all of them, not relieving the
+    slowest.  So the start cools with the widest smooth max, ``n`` = the
+    larger of ``nS·nM`` push and ``nM·nR`` shuffle links: exactly 0.3 up
+    to :data:`_TAU_TERMS_REF` terms, then ``0.3 · _TAU_TERMS_REF / n``,
+    but never colder than ``_TAU_SMOOTHING_FLOOR / ln n``, below which
+    Adam sees little but the slowest link's gradient.  The sweep this
+    rests on is in PERF.md, section 6."""
+    n = max(nS * nM, nM * nR)
+    if n <= _TAU_TERMS_REF:
+        return 0.3
+    return max(0.3 * _TAU_TERMS_REF / n, _TAU_SMOOTHING_FLOOR / math.log(n))
 
 
 def _initial_logits(platform: Platform, n_restarts: int, seed: int):
@@ -558,10 +615,13 @@ def _run_solver_many(
                        for a in (*arrs, lx0, ly0, xf, yf, scales))
         tracing.count("plan.h2d", len(inputs))
         inputs = jax.device_put(inputs)
-    with tracing.span("geoplan.plan.solve"):
+    nS, nM, nR = platforms[0].nS, platforms[0].nM, platforms[0].nR
+    tau0_frac = _anneal_tau0_frac(nS, nM, nR)
+    with tracing.span("geoplan.plan.solve", params=B * n_restarts * (
+            nS * nM + nR), steps=steps, tau0_frac=tau0_frac):
         best = _solve_batch_many(
             inputs[:6], *inputs[6:], loss_kind, tuple(barriers), opt_x,
-            opt_y, steps,
+            opt_y, steps, tau0_frac=tau0_frac,
         )
         jax.block_until_ready(best)
     with tracing.span("geoplan.plan.fetch"):

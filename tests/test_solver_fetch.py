@@ -1,7 +1,8 @@
 """The solver picks each request's best restart on the device: what
 ``_run_solver_many`` returns is bit for bit what the whole anneal's
-restarts give when the best is picked on the host, and the inputs it puts
-are the float32 casts of the host's float64 arrays.
+restarts and the un-annealed warm starts give when the best is picked on
+the host, and the inputs it puts are the float32 casts of the host's
+float64 arrays.
 
 The solver runs at 4 restarts x 40 steps on 8-node PlanetLab platforms."""
 import jax
@@ -36,13 +37,19 @@ def _recorded(monkeypatch):
     return calls
 
 
-def _reference(args):
-    """The anneal over every restart, the best picked on the host."""
-    anneal = jax.jit(opt._anneal_restarts, static_argnames=STATICS)
+def _reference(args, kw):
+    """The anneal over every restart, then the un-annealed warm starts,
+    the best of them all picked on the host."""
+    anneal = jax.jit(opt._anneal_restarts,
+                     static_argnames=STATICS + ("tau0_frac",))
     arrs, lx, ly, xf, yf, sc, loss_kind, barriers, opt_x, opt_y, steps = args
-    xs, ys, exact = map(np.asarray, anneal(
-        arrs, lx, ly, xf, yf, sc, loss_kind=loss_kind, barriers=barriers,
-        opt_x=opt_x, opt_y=opt_y, steps=steps))
+    statics = dict(loss_kind=loss_kind, barriers=barriers, opt_x=opt_x,
+                   opt_y=opt_y)
+    annealed = anneal(arrs, lx, ly, xf, yf, sc, steps=steps, **kw, **statics)
+    w = opt._WARM_STARTS
+    warm = anneal(arrs, lx[:, :w], ly[:, :w], xf, yf, sc, steps=0, **statics)
+    xs, ys, exact = (np.concatenate([np.asarray(a), np.asarray(b)], axis=1)
+                     for a, b in zip(annealed, warm))
     out = []
     for b in range(exact.shape[0]):
         best = int(np.argmin(exact[b]))
@@ -55,6 +62,8 @@ def _reference(args):
 @pytest.mark.parametrize("loss_kind,opt_x,opt_y,fixed", [
     ("e2e", True, True, False),
     ("shuffle", False, True, True),
+    # the locality warm start beats every annealed push plan here
+    ("push", True, False, False),
 ])
 def test_best_restart_on_the_device_is_the_hosts_pick(
         monkeypatch, b, loss_kind, opt_x, opt_y, fixed):
@@ -66,7 +75,7 @@ def test_best_restart_on_the_device_is_the_hosts_pick(
     got = opt._run_solver_many(platforms, loss_kind, BARRIERS_GGL, opt_x,
                                opt_y, fixed_x, None, R, STEPS, seeds)
     (args, kw), = calls
-    assert not kw and args[10] == STEPS
+    assert kw == {"tau0_frac": 0.3} and args[10] == STEPS
     # the inputs went over as the float32 casts of the host's arrays
     raw = [p.as_arrays() for p in platforms]
     for i, a in enumerate(args[0]):
@@ -82,7 +91,7 @@ def test_best_restart_on_the_device_is_the_hosts_pick(
               for p in platforms]
     np.testing.assert_array_equal(np.asarray(args[5]),
                                   np.asarray(scales, np.float32))
-    want = _reference(args)
+    want = _reference(args, kw)
     assert len(got) == len(want) == b
     for (x, y, obj), (wx, wy, wobj) in zip(got, want):
         np.testing.assert_array_equal(x, wx)

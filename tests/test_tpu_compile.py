@@ -91,3 +91,29 @@ def test_solver_compiles_at_thousand_node_tier(one_chip):
     ).compile()
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < 16 * 2**30
+
+
+def test_solver_compiles_at_planetlab512(one_chip):
+    """The ``planetlab512`` cell's call: 8 requests x 24 restarts over 512
+    sites, 500 steps, the sized start; the anneal's state fits one chip
+    with room to spare."""
+    from repro.core.optimize import _anneal_tau0_frac, _solve_batch_many
+
+    B, R, n = 8, 24, 512
+    f32 = jnp.float32
+    arrs = tuple(
+        _spec((B,) + s, f32, one_chip)
+        for s in [(n,), (n, n), (n, n), (n,), (n,), ()]
+    )
+    compiled = _solve_batch_many._jitted.lower(
+        arrs,
+        _spec((B, R, n, n), f32, one_chip),
+        _spec((B, R, n), f32, one_chip),
+        _spec((B, n, n), f32, one_chip),
+        _spec((B, n), f32, one_chip),
+        _spec((B,), f32, one_chip),
+        loss_kind="e2e", barriers=("G", "G", "G"), opt_x=True, opt_y=True,
+        steps=500, tau0_frac=_anneal_tau0_frac(n, n, n),
+    ).compile()
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 4 * 2**30
