@@ -500,9 +500,53 @@ def _best_restart(xs, ys, exact):
     return pick(xs), pick(ys), pick(exact)
 
 
-#: the warm starts :func:`_initial_logits` puts first: uniform, locality
-#: and bandwidth-greedy
+#: the warm starts :func:`_warm_logits` gives, first among a request's
+#: restarts: uniform, locality and bandwidth-greedy
 _WARM_STARTS = 3
+
+
+@_counted_solver(static_argnames=("n_restarts",))
+def _start_logits(warm_x, warm_y, seed_words, n_restarts: int):
+    """The fresh-plan anneal's start logits, float32 ``(B, R, nS, nM)`` and
+    ``(B, R, nR)`` with ``R = n_restarts``: each request's warm starts
+    ``warm_x`` ``(B, W, nS, nM)`` and ``warm_y`` ``(B, W, nR)`` as given,
+    then ``R - W`` random restarts drawn here, on the device.
+
+    Restart ``r`` draws σ ~ U(0.3, 3.0), then its logits ~ N(0, σ²), from
+    ``fold_in(key, r)``, where ``key`` holds the request's seed as its two
+    threefry words (``seed_words`` ``(B, 2)`` uint32, see
+    :func:`_seed_words`).  So a request's starts depend on its seed alone,
+    not on its batch or on ``R``, and threefry gives the same bits on every
+    backend."""
+    w = warm_x.shape[1]
+    nS, nM, nR = warm_x.shape[2], warm_x.shape[3], warm_y.shape[2]
+
+    def one_request(words):
+        key = jax.random.wrap_key_data(words, impl="threefry2x32")
+
+        def one_restart(r):
+            k_sigma, k_x, k_y = jax.random.split(jax.random.fold_in(key, r), 3)
+            sigma = jax.random.uniform(k_sigma, minval=0.3, maxval=3.0)
+            return (sigma * jax.random.normal(k_x, (nS, nM)),
+                    sigma * jax.random.normal(k_y, (nR,)))
+
+        return jax.vmap(one_restart)(jnp.arange(w, n_restarts))
+
+    rx, ry = jax.vmap(one_request)(seed_words)
+    return (jnp.concatenate([warm_x, rx], axis=1),
+            jnp.concatenate([warm_y, ry], axis=1))
+
+
+def _seed_words(seeds: Sequence[int]) -> np.ndarray:
+    """Each seed as threefry key data, ``(B, 2)`` uint32: its high and low
+    32-bit words, so no two seeds below ``2**64`` share a key."""
+    words = []
+    for s in map(int, seeds):
+        if not 0 <= s < 2**64:
+            raise ValueError(f"a solver seed lies in [0, 2**64), got {s}")
+        words.append((s >> 32, s & 0xFFFFFFFF))
+    return np.array(words, dtype=np.uint32).reshape(len(words), 2)
+
 
 #: terms of the planetlab8 testbed's widest smooth max (8 x 8 push links);
 #: up to here the fresh-plan anneal starts at ``tau0_frac`` 0.3
@@ -531,23 +575,33 @@ def _anneal_tau0_frac(nS: int, nM: int, nR: int) -> float:
     return max(0.3 * _TAU_TERMS_REF / n, _TAU_SMOOTHING_FLOOR / math.log(n))
 
 
-def _initial_logits(platform: Platform, n_restarts: int, seed: int):
-    """Random inits plus deterministic warm starts (uniform, local push,
-    bandwidth-greedy), as float32 host arrays ``(R, nS, nM)``, ``(R, nR)``."""
-    rng = np.random.default_rng(seed)
-    nS, nM, nR = platform.nS, platform.nM, platform.nR
+def _warm_logits(platform: Platform):
+    """The deterministic warm starts (uniform, local push,
+    bandwidth-greedy for ``x``; uniform, compute-greedy, mean shuffle
+    bandwidth for ``y``) as float64 host arrays ``(W, nS, nM)``,
+    ``(W, nR)``."""
     eps = 1e-9
-
-    warm_x = [
-        np.zeros((nS, nM)),  # uniform
+    warm_x = np.stack([
+        np.zeros((platform.nS, platform.nM)),  # uniform
         np.log(local_push_plan(platform).x + eps),  # locality
         np.log(platform.B_sm / platform.B_sm.max() + eps),  # bandwidth-greedy
-    ]
-    warm_y = [
-        np.zeros(nR),  # uniform
+    ])
+    warm_y = np.stack([
+        np.zeros(platform.nR),  # uniform
         np.log(platform.C_r / platform.C_r.max() + eps),  # compute-greedy
         np.log(np.mean(platform.B_mr, axis=0) / platform.B_mr.max() + eps),
-    ]
+    ])
+    return warm_x, warm_y
+
+
+def _initial_logits(platform: Platform, n_restarts: int, seed: int):
+    """The warm starts of :func:`_warm_logits`, then random inits drawn on
+    the host, as float32 host arrays ``(R, nS, nM)``, ``(R, nR)``: the
+    re-plan, joint, residual and pipeline solves' starts (a fresh plan
+    draws its own on the device, :func:`_start_logits`)."""
+    rng = np.random.default_rng(seed)
+    nS, nM, nR = platform.nS, platform.nM, platform.nR
+    warm_x, warm_y = _warm_logits(platform)
     lx = list(warm_x)
     ly = list(warm_y)
     while len(lx) < n_restarts:
@@ -557,6 +611,14 @@ def _initial_logits(platform: Platform, n_restarts: int, seed: int):
     lx = np.stack(lx[:n_restarts]).astype(np.float32)
     ly = np.stack(ly[:n_restarts]).astype(np.float32)
     return lx, ly
+
+
+def _put(*arrays):
+    """Put host arrays on the device in one transfer, counted as
+    ``plan.h2d`` arrays and ``plan.h2d_bytes``."""
+    tracing.count("plan.h2d", len(arrays))
+    tracing.count("plan.h2d_bytes", sum(a.nbytes for a in arrays))
+    return jax.device_put(arrays)
 
 
 def _run_solver_many(
@@ -578,50 +640,63 @@ def _run_solver_many(
     capacities/seeds are free.  Returns one ``(x, y, exact)`` per request,
     the best restart under the exact hard-max model, float64-renormalized.
 
-    One host-to-device put of the 11 float32 inputs and one fetch of the 3
-    outputs, whatever ``B`` (counted as ``plan.h2d`` and ``plan.d2h``
-    arrays).  Spans ``geoplan.plan.prep`` (host inputs and the put),
-    ``.solve`` (the dispatch through the device's finish) and ``.fetch``
-    (the fetch and the renormalization) split the call.
+    Two puts and one fetch, whatever ``B`` (counted as ``plan.h2d`` arrays
+    and ``plan.h2d_bytes``, and ``plan.d2h`` arrays): first the warm
+    starts and the seeds, from which :func:`_start_logits` draws the random
+    restarts on the device while the host prepares the rest; then the
+    platforms' float32 arrays, the scales and the fixed plans the solve
+    reads.  Spans ``geoplan.plan.prep`` (host inputs, the puts and the
+    draw's dispatch), ``.solve`` (the solver's dispatch through the
+    device's finish) and ``.fetch`` (the fetch and the renormalization)
+    split the call.
     """
     B = len(platforms)
+    nS, nM, nR = platforms[0].nS, platforms[0].nM, platforms[0].nR
     with tracing.span("geoplan.plan.prep"):
+        w = min(_WARM_STARTS, n_restarts)
+        warm = [_warm_logits(p) for p in platforms]
+        starts = (np.stack([x[:w] for x, _ in warm]).astype(np.float32),
+                  np.stack([y[:w] for _, y in warm]).astype(np.float32))
+        if n_restarts > w:
+            lx0, ly0 = _start_logits(*_put(*starts, _seed_words(seeds)),
+                                     n_restarts)
+        else:
+            lx0, ly0 = _put(*starts)
         raw = [p.as_arrays() for p in platforms]
-        arrs = tuple(
+        arrs = [
             np.stack([np.asarray(r[i], dtype=np.float64) for r in raw])
             for i in range(6)
-        )
-        if x_fixed_list is None:
-            x_fixed_list = [None] * B
-        if y_fixed_list is None:
-            y_fixed_list = [None] * B
-        xf = np.stack([
-            uniform_plan(p).x if x is None else np.asarray(x)
-            for p, x in zip(platforms, x_fixed_list)
-        ])
-        yf = np.stack([
-            uniform_plan(p).y if y is None else np.asarray(y)
-            for p, y in zip(platforms, y_fixed_list)
-        ])
+        ]
         scales = np.array([
             max(makespan(p, uniform_plan(p), barriers=barriers), 1e-6)
             for p in platforms
         ])
-        inits = [_initial_logits(p, n_restarts, s)
-                 for p, s in zip(platforms, seeds)]
-        lx0 = np.stack([lx for lx, _ in inits])
-        ly0 = np.stack([ly for _, ly in inits])
-        inputs = tuple(np.asarray(a, np.float32)
-                       for a in (*arrs, lx0, ly0, xf, yf, scales))
-        tracing.count("plan.h2d", len(inputs))
-        inputs = jax.device_put(inputs)
-    nS, nM, nR = platforms[0].nS, platforms[0].nM, platforms[0].nR
+        # a fixed plan goes over only where the solve reads it; elsewhere
+        # zeros made on the device stand in
+        fixed = {}
+        if not opt_x:
+            fixed["x"] = np.stack([
+                uniform_plan(p).x if x is None else np.asarray(x)
+                for p, x in zip(platforms, x_fixed_list or [None] * B)
+            ])
+        if not opt_y:
+            fixed["y"] = np.stack([
+                uniform_plan(p).y if y is None else np.asarray(y)
+                for p, y in zip(platforms, y_fixed_list or [None] * B)
+            ])
+        put = _put(*(np.asarray(a, np.float32)
+                     for a in (*arrs, scales, *fixed.values())))
+        arrs, scales = tuple(put[:6]), put[6]
+        fixed = dict(zip(fixed, put[7:]))
+        xf = fixed["x"] if "x" in fixed else jnp.zeros((B, nS, nM),
+                                                       jnp.float32)
+        yf = fixed["y"] if "y" in fixed else jnp.zeros((B, nR), jnp.float32)
     tau0_frac = _anneal_tau0_frac(nS, nM, nR)
     with tracing.span("geoplan.plan.solve", params=B * n_restarts * (
             nS * nM + nR), steps=steps, tau0_frac=tau0_frac):
         best = _solve_batch_many(
-            inputs[:6], *inputs[6:], loss_kind, tuple(barriers), opt_x,
-            opt_y, steps, tau0_frac=tau0_frac,
+            arrs, lx0, ly0, xf, yf, scales, loss_kind,
+            tuple(barriers), opt_x, opt_y, steps, tau0_frac=tau0_frac,
         )
         jax.block_until_ready(best)
     with tracing.span("geoplan.plan.fetch"):
@@ -841,7 +916,8 @@ def optimize_plan_batch(
     round-off) to calling :func:`optimize_plan` per request.
 
     Span ``geoplan.plan_many`` covers the call, with attrs ``requests``
-    and the transfers it made each way (``plan.h2d``, ``plan.d2h``);
+    and the transfers it made each way (``plan.h2d``, ``plan.d2h``) and
+    the bytes it put (``plan.h2d_bytes``);
     ``geoplan.plan.price`` covers a group's float64 pricing.
     """
     platforms = list(platforms)
@@ -867,7 +943,8 @@ def optimize_plan_batch(
     for g, p in enumerate(platforms):
         groups.setdefault((p.nS, p.nM, p.nR), []).append(g)
     results: "list[Optional[PlanResult]]" = [None] * len(platforms)
-    with tracing.span("geoplan.plan_many", ("plan.h2d", "plan.d2h"),
+    with tracing.span("geoplan.plan_many",
+                      ("plan.h2d", "plan.h2d_bytes", "plan.d2h"),
                       requests=len(platforms)):
         for idxs in groups.values():
             planned = _plan_group(

@@ -25,9 +25,11 @@ CONFIG = json.loads((BENCH / "configs" / "planetlab512.json").read_text())
 SOLVER = CONFIG["solver"]
 BARRIERS = CONFIG["barriers"]
 SITES = 128
-#: the fixed start fails on some requests and not on others: over seeds
-#: 1-8 its mean of the two reads 0.77, 0.45, 0.67, 1.00, 0.50, 0.86, 0.79
-#: and 0.96, the sized start's 0.35-0.52.  On seed 4 it fails on both
+#: the fixed start fails on some requests and not on others: with the
+#: random restarts drawn on the device, over seeds 1-8 its mean of the two
+#: reads 0.74, 0.72, 0.85, 1.02, 0.46, 0.85, 0.88 and 1.09, the sized
+#: start's 0.36-0.42.  On seeds 3, 4 and 8 it fails on both (0.82 and
+#: 1.21 on seed 4)
 SEED = 4
 
 

@@ -2,7 +2,8 @@
 ``_run_solver_many`` returns is bit for bit what the whole anneal's
 restarts and the un-annealed warm starts give when the best is picked on
 the host, and the inputs it puts are the float32 casts of the host's
-float64 arrays.
+float64 arrays, the start logits the host's warm starts and then the
+restarts drawn on the device.
 
 The solver runs at 4 restarts x 40 steps on 8-node PlanetLab platforms."""
 import jax
@@ -83,10 +84,16 @@ def test_best_restart_on_the_device_is_the_hosts_pick(
         np.testing.assert_array_equal(np.asarray(a),
                                       np.asarray(jnp.asarray(want,
                                                              jnp.float32)))
-    for pos, init in ((1, 0), (2, 1)):
-        np.testing.assert_array_equal(np.asarray(args[pos]), np.stack(
-            [opt._initial_logits(p, R, s)[init]
-             for p, s in zip(platforms, seeds)]))
+    # the start logits are the host's warm starts, then the device's draw
+    w = opt._WARM_STARTS
+    warm = [opt._warm_logits(p) for p in platforms]
+    wx = np.stack([x[:w] for x, _ in warm]).astype(np.float32)
+    wy = np.stack([y[:w] for _, y in warm]).astype(np.float32)
+    starts = opt._start_logits(wx, wy, opt._seed_words(seeds), R)
+    for pos, init, host in ((1, 0, wx), (2, 1, wy)):
+        np.testing.assert_array_equal(np.asarray(args[pos]),
+                                      np.asarray(starts[init]))
+        np.testing.assert_array_equal(np.asarray(args[pos])[:, :w], host)
     scales = [max(makespan(p, uniform_plan(p), barriers=BARRIERS_GGL), 1e-6)
               for p in platforms]
     np.testing.assert_array_equal(np.asarray(args[5]),

@@ -117,3 +117,20 @@ def test_solver_compiles_at_planetlab512(one_chip):
     ).compile()
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 4 * 2**30
+
+
+def test_start_logits_compiles_at_planetlab512(one_chip):
+    """The draw of the ``planetlab512`` call's starts: 8 requests' 3 warm
+    starts in, 24 restarts out, their 201 MB the program's output."""
+    from repro.core.optimize import _start_logits
+
+    B, W, R, n = 8, 3, 24, 512
+    compiled = _start_logits._jitted.lower(
+        _spec((B, W, n, n), jnp.float32, one_chip),
+        _spec((B, W, n), jnp.float32, one_chip),
+        _spec((B, 2), jnp.uint32, one_chip),
+        n_restarts=R,
+    ).compile()
+    mem = compiled.memory_analysis()
+    assert mem.output_size_in_bytes >= 4 * B * R * (n * n + n)
+    assert mem.temp_size_in_bytes < 2**30

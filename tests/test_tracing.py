@@ -34,6 +34,15 @@ def ring():
     tracing.clear()
 
 
+def _h2d_bytes(p, b):
+    """Bytes a call of ``b`` same-shape requests puts, all 4-byte words:
+    per request the 3 warm starts of ``x`` and of ``y``, 2 seed words, the
+    6 platform arrays and the scale."""
+    nS, nM, nR = p.nS, p.nM, p.nR
+    return 4 * b * (3 * (nS * nM + nR) + 2
+                    + nS + nS * nM + nM * nR + nM + nR + 1 + 1)
+
+
 def _plan(svc, b):
     platforms = [planetlab_platform(1, alpha=1.0, seed=s) for s in range(b)]
     return svc.plan_many(platforms, seeds=list(range(b)))
@@ -62,7 +71,7 @@ def test_nothing_is_recorded_outside_a_session(svc, ring):
     _plan(svc, 1)
     assert tracing.spans() == []
     after = tracing.counters()
-    assert after["plan.h2d"] - before["plan.h2d"] == 11
+    assert after["plan.h2d"] - before["plan.h2d"] == 10
     assert after["plan.d2h"] - before["plan.d2h"] == 3
     with tracing.recording():
         pass
@@ -86,13 +95,17 @@ def test_one_root_per_call_with_its_stages_and_transfers(svc, ring, b):
         assert k.root_id == root.span_id
         assert root.start_ns <= k.start_ns <= k.end_ns <= root.end_ns
     assert all(a.end_ns <= z.start_ns for a, z in zip(kids, kids[1:]))
-    assert root.attrs == {"requests": b, "plan.h2d": 11, "plan.d2h": 3}
-    assert after["plan.h2d"] - before["plan.h2d"] == 11
+    p = planetlab_platform(1, alpha=1.0, seed=0)
+    assert root.attrs == {"requests": b, "plan.h2d": 10, "plan.d2h": 3,
+                          "plan.h2d_bytes": _h2d_bytes(p, b)}
+    assert after["plan.h2d"] - before["plan.h2d"] == 10
     assert after["plan.d2h"] - before["plan.d2h"] == 3
     assert len(tracing.spans()) == 5
 
 
 def test_each_shape_group_makes_one_put_and_one_fetch(svc, ring):
+    """Whatever its size, a shape group puts the same arrays (the draw's
+    inputs, then the solve's) and makes one fetch."""
     platforms = [planetlab_platform(1, alpha=1.0, seed=s) for s in range(2)]
     platforms += [two_cluster_example(alpha=a) for a in (0.5, 1.0, 2.0)]
     assert len({(p.nS, p.nM, p.nR) for p in platforms}) == 2
@@ -102,8 +115,10 @@ def test_each_shape_group_makes_one_put_and_one_fetch(svc, ring):
         assert len(svc.plan_many(platforms, seeds=list(range(5)))) == 5
     (root, kids), = _calls("geoplan.plan_many")
     assert [k.name for k in kids] == list(STAGES) * 2
-    assert root.attrs == {"requests": 5, "plan.h2d": 2 * 11,
-                          "plan.d2h": 2 * 3}
+    assert root.attrs == {"requests": 5, "plan.h2d": 2 * 10,
+                          "plan.d2h": 2 * 3, "plan.h2d_bytes": sum(
+                              _h2d_bytes(p, b) for p, b in (
+                                  (platforms[0], 2), (platforms[2], 3)))}
 
 
 def test_the_ring_keeps_the_newest_spans(ring):
