@@ -247,6 +247,44 @@ def _adam_anneal(loss, params0, steps: int, scale, lr, tau0_frac, tau1_frac):
     return params
 
 
+def _annealed(objective, logits_x0, logits_y0, scale, steps: int, lr,
+              tau0_frac, tau1_frac, x_fixed=None, y_fixed=None):
+    """The restart loop every solver kernel shares: for each restart of
+    ``logits_x0`` ``(R, ...)`` and ``logits_y0`` ``(R, ...)``, anneal
+    ``objective(x, y, mx, pmax) / scale`` with :func:`_adam_anneal` under
+    :func:`smooth_ops`, then re-price the result under :func:`hard_ops`.
+    ``x`` and ``y`` are the softmax of their logits, or ``x_fixed`` /
+    ``y_fixed`` where one is given.  Returns per-restart ``(x, y, exact)``.
+
+    At 0 steps there is no scan (and no gradient) to trace: the starts
+    are priced as they are.  Pure JAX, traced inside each kernel's own
+    jitted body, so it adds no executable."""
+
+    def build(params):
+        x = (jax.nn.softmax(params["x"], axis=-1) if x_fixed is None
+             else x_fixed)
+        y = (jax.nn.softmax(params["y"], axis=-1) if y_fixed is None
+             else y_fixed)
+        return x, y
+
+    def loss(params, tau):
+        mx, pmax = smooth_ops(tau)
+        x, y = build(params)
+        return objective(x, y, mx, pmax) / scale
+
+    def one_restart(lx0, ly0):
+        params = {"x": lx0, "y": ly0}
+        if steps:
+            params = _adam_anneal(
+                loss, params, steps, scale, lr, tau0_frac, tau1_frac,
+            )
+        x, y = build(params)
+        mx, pmax = hard_ops()
+        return x, y, objective(x, y, mx, pmax)
+
+    return jax.vmap(one_restart)(logits_x0, logits_y0)
+
+
 # ---------------------------------------------------------------------------
 # solver service plumbing: shape-keyed executable cache with hit/miss/compile
 # counters
@@ -407,28 +445,11 @@ def _anneal_restarts(
     loss_core = _objective_fn(loss_kind, barriers)
 
     def one_request(arrs_b, lx_b, ly_b, xf, yf, sc):
-        def build(params):
-            x = jax.nn.softmax(params["x"], axis=-1) if opt_x else xf
-            y = jax.nn.softmax(params["y"], axis=-1) if opt_y else yf
-            return x, y
-
-        def loss(params, tau):
-            mx, pmax = smooth_ops(tau)
-            x, y = build(params)
-            return loss_core(arrs_b, x, y, mx, pmax) / sc
-
-        def one_restart(lx0, ly0):
-            params = {"x": lx0, "y": ly0}
-            if steps:  # no scan (and no gradient) to trace for 0 steps
-                params = _adam_anneal(
-                    loss, params, steps, sc, lr, tau0_frac, tau1_frac,
-                )
-            x, y = build(params)
-            mx, pmax = hard_ops()
-            exact = loss_core(arrs_b, x, y, mx, pmax)
-            return x, y, exact
-
-        return jax.vmap(one_restart)(lx_b, ly_b)
+        return _annealed(
+            functools.partial(loss_core, arrs_b), lx_b, ly_b, sc, steps, lr,
+            tau0_frac, tau1_frac, x_fixed=None if opt_x else xf,
+            y_fixed=None if opt_y else yf,
+        )
 
     return jax.vmap(one_request)(
         arrs, logits_x0, logits_y0, x_fixed, y_fixed, scale
@@ -710,26 +731,6 @@ def _run_solver_many(
     return out
 
 
-def _run_solver(
-    platform: Platform,
-    loss_kind: str,
-    barriers,
-    opt_x: bool,
-    opt_y: bool,
-    x_fixed: Optional[np.ndarray],
-    y_fixed: Optional[np.ndarray],
-    n_restarts: int,
-    steps: int,
-    seed: int,
-) -> Tuple[np.ndarray, np.ndarray, float]:
-    """One solve request — a batch of one through the vmapped service
-    path, so single plans and batched plans share one executable cache."""
-    return _run_solver_many(
-        [platform], loss_kind, barriers, opt_x, opt_y,
-        [x_fixed], [y_fixed], n_restarts, steps, [seed],
-    )[0]
-
-
 # ---------------------------------------------------------------------------
 # built-in planners
 # ---------------------------------------------------------------------------
@@ -746,59 +747,73 @@ def _local_push_planner(platform, barriers, *, n_restarts, steps, seed, fixed_x)
     return plan, makespan(platform, plan, barriers)
 
 
-@register_planner("myopic_push")
-def _myopic_push_planner(platform, barriers, *, n_restarts, steps, seed, fixed_x):
-    x, _, obj = _run_solver(
-        platform, "push", barriers, True, False, None, None,
-        n_restarts, steps, seed,
-    )
-    return ExecutionPlan(x=x, y=uniform_plan(platform).y, meta="myopic_push"), obj
-
-
-@register_planner("myopic_multi")
-def _myopic_multi_planner(platform, barriers, *, n_restarts, steps, seed, fixed_x):
+#: the one definition of what each built-in solver mode solves: its
+#: rounds of ``(loss_kind, opt_x, opt_y)``, in order
+_SOLVER_MODES = {
+    "myopic_push": (("push", True, False),),
     # locally-optimal push, then locally-optimal shuffle given that push
-    x, _, _ = _run_solver(
-        platform, "push", barriers, True, False, None, None,
-        n_restarts, steps, seed,
-    )
-    _, y, obj = _run_solver(
-        platform, "shuffle", barriers, False, True, x, None,
-        n_restarts, steps, seed + 1,
-    )
-    return ExecutionPlan(x=x, y=y, meta="myopic_multi"), obj
+    "myopic_multi": (("push", True, False), ("shuffle", False, True)),
+    "e2e_push": (("e2e", True, False),),
+    "e2e_shuffle": (("e2e", False, True),),
+    "e2e_multi": (("e2e", True, True),),
+}
 
 
-@register_planner("e2e_push")
-def _e2e_push_planner(platform, barriers, *, n_restarts, steps, seed, fixed_x):
-    x, _, obj = _run_solver(
-        platform, "e2e", barriers, True, False, None, None,
-        n_restarts, steps, seed,
-    )
-    return ExecutionPlan(x=x, y=uniform_plan(platform).y, meta="e2e_push"), obj
+def _plan_group(platforms, mode, barriers, n_restarts, steps, seeds,
+                fixed_xs) -> "list[Tuple[ExecutionPlan, float]]":
+    """Plan one same-shape group of requests.
+
+    A solver mode runs its rounds (:data:`_SOLVER_MODES`), each one
+    batched dispatch of :func:`_run_solver_many`.  Round ``k`` is seeded
+    at ``seed + k``; the ``x`` it holds fixed is the previous round's,
+    else the caller's ``fixed_x``, else uniform.  The plan takes the last
+    optimised ``x`` and ``y`` (else the fixed or uniform one) and the last
+    round's objective.  Any other mode plans each request through its
+    registered planner."""
+    if mode not in _SOLVER_MODES:
+        planner = get_planner(mode)
+        return [
+            planner(p, barriers, n_restarts=n_restarts, steps=steps, seed=s,
+                    fixed_x=fx)
+            for p, s, fx in zip(platforms, seeds, fixed_xs)
+        ]
+    xs, ys = list(fixed_xs), [None] * len(platforms)
+    for k, (loss_kind, opt_x, opt_y) in enumerate(_SOLVER_MODES[mode]):
+        solved = _run_solver_many(platforms, loss_kind, barriers, opt_x,
+                                  opt_y, xs, None, n_restarts, steps,
+                                  [s + k for s in seeds])
+        if opt_x:
+            xs = [x for x, _, _ in solved]
+        if opt_y:
+            ys = [y for _, y, _ in solved]
+    return [
+        (ExecutionPlan(x=uniform_plan(p).x if x is None else np.asarray(x),
+                       y=uniform_plan(p).y if y is None else y, meta=mode),
+         obj)
+        for p, x, y, (_, _, obj) in zip(platforms, xs, ys, solved)
+    ]
 
 
-@register_planner("e2e_shuffle")
-def _e2e_shuffle_planner(platform, barriers, *, n_restarts, steps, seed, fixed_x):
-    _, y, obj = _run_solver(
-        platform, "e2e", barriers, False, True, fixed_x, None,
-        n_restarts, steps, seed,
-    )
-    x = uniform_plan(platform).x if fixed_x is None else np.asarray(fixed_x)
-    return ExecutionPlan(x=x, y=y, meta="e2e_shuffle"), obj
+def _planner_for(mode: str) -> Callable:
+    """A built-in solver mode's registered planner: a batch of one
+    through :func:`_plan_group`."""
+
+    def planner(platform, barriers, *, n_restarts, steps, seed, fixed_x):
+        return _plan_group([platform], mode, barriers, n_restarts, steps,
+                           [seed], [fixed_x])[0]
+
+    return planner
 
 
-@register_planner("e2e_multi")
-def _e2e_multi_planner(platform, barriers, *, n_restarts, steps, seed, fixed_x):
-    x, y, obj = _run_solver(
-        platform, "e2e", barriers, True, True, None, None,
-        n_restarts, steps, seed,
-    )
-    return ExecutionPlan(x=x, y=y, meta="e2e_multi"), obj
+register_planner("myopic_push", _planner_for("myopic_push"))
+register_planner("myopic_multi", _planner_for("myopic_multi"))
+register_planner("e2e_push", _planner_for("e2e_push"))
+register_planner("e2e_shuffle", _planner_for("e2e_shuffle"))
+register_planner("e2e_multi", _planner_for("e2e_multi"))
 
 
 # ---------------------------------------------------------------------------
-# public entry point
+# public entry points: one plan, and the planner service's batch
 # ---------------------------------------------------------------------------
 
 def optimize_plan(
@@ -817,79 +832,13 @@ def optimize_plan(
     ``fixed_x`` pins the push matrix for the shuffle-only modes
     (``e2e_shuffle``); defaults to the uniform push of Equation 15.  This is
     how the collective/MoE planners express "the push side is dictated by
-    the system" (identity routing).
+    the system" (identity routing).  This is a batch of one through
+    :func:`optimize_plan_batch`.
     """
-    planner = get_planner(mode)
-    barriers = tuple(barriers)
-    plan, obj = planner(
-        platform, barriers,
-        n_restarts=n_restarts, steps=steps, seed=seed, fixed_x=fixed_x,
-    )
-    return PlanResult(
-        plan=plan,
-        makespan=makespan(platform, plan, barriers),
-        breakdown=phase_breakdown(platform, plan, barriers),
-        mode=mode,
-        barriers=barriers,
-        objective=float(obj),
-    )
-
-
-# ---------------------------------------------------------------------------
-# planner-as-a-service: batched concurrent solve requests
-# ---------------------------------------------------------------------------
-
-#: built-in modes whose planner is exactly one `_run_solver` call —
-#: batchable as (loss_kind, opt_x, opt_y).  ``myopic_multi`` (two chained
-#: solves) is batched as two rounds; anything else falls back to a
-#: per-request planner loop.
-_BATCHED_SOLVES = {
-    "myopic_push": ("push", True, False),
-    "e2e_push": ("e2e", True, False),
-    "e2e_shuffle": ("e2e", False, True),
-    "e2e_multi": ("e2e", True, True),
-}
-
-
-def _plan_group(platforms, mode, barriers, n_restarts, steps, seeds,
-                fixed_xs) -> "list[Tuple[ExecutionPlan, float]]":
-    """Plan one same-shape group of requests, batching the solver
-    dispatches where the mode allows; mirrors the built-in planners'
-    construction exactly (same warm starts, seeds, and plan assembly)."""
-    if mode == "myopic_multi":
-        # locally-optimal push, then locally-optimal shuffle given that
-        # push — two batched rounds, round 2 reseeded at seed+1 like the
-        # sequential planner
-        r1 = _run_solver_many(platforms, "push", barriers, True, False,
-                              None, None, n_restarts, steps, seeds)
-        xs = [x for x, _, _ in r1]
-        r2 = _run_solver_many(platforms, "shuffle", barriers, False, True,
-                              xs, None, n_restarts, steps,
-                              [s + 1 for s in seeds])
-        return [
-            (ExecutionPlan(x=x, y=y, meta="myopic_multi"), obj)
-            for x, (_, y, obj) in zip(xs, r2)
-        ]
-    if mode in _BATCHED_SOLVES:
-        loss_kind, opt_x, opt_y = _BATCHED_SOLVES[mode]
-        xf = fixed_xs if not opt_x else [None] * len(platforms)
-        solved = _run_solver_many(platforms, loss_kind, barriers, opt_x,
-                                  opt_y, xf, None, n_restarts, steps, seeds)
-        plans = []
-        for p, fx, (x, y, obj) in zip(platforms, fixed_xs, solved):
-            if not opt_y:
-                y = uniform_plan(p).y
-            if not opt_x:
-                x = uniform_plan(p).x if fx is None else np.asarray(fx)
-            plans.append((ExecutionPlan(x=x, y=y, meta=mode), obj))
-        return plans
-    # heuristic or externally-registered mode: per-request dispatch
-    planner = get_planner(mode)
-    return [
-        planner(p, barriers, n_restarts=n_restarts, steps=steps, seed=s,
-                fixed_x=fx)
-        for p, s, fx in zip(platforms, seeds, fixed_xs)
-    ]
+    return optimize_plan_batch(
+        [platform], mode, barriers=barriers, n_restarts=n_restarts,
+        steps=steps, seeds=[seed], fixed_x=[fixed_x],
+    )[0]
 
 
 def optimize_plan_batch(
@@ -906,14 +855,15 @@ def optimize_plan_batch(
     shapes allow — the batched front door of the solver service.
 
     Requests are grouped by ``(nS, nM, nR)``; each same-shape group of a
-    solver-backed mode runs as **one** vmapped dispatch
-    (:func:`_solve_batch_many`), sharing a single cached executable with
-    every other same-shape solve in the process.  ``seeds`` gives one seed
-    per request (default ``seed + 17*g``, matching what the
-    ``independent`` schedule policy always used); ``fixed_x`` one pinned
-    push matrix per request for the shuffle-only modes.  Results are
-    per-request :class:`PlanResult`\\ s, identical (to float32 vmap
-    round-off) to calling :func:`optimize_plan` per request.
+    solver-backed mode runs as **one** vmapped dispatch per round of the
+    mode (:func:`_plan_group`, :func:`_solve_batch_many`), sharing a
+    single cached executable with every other same-shape solve in the
+    process.  ``seeds`` gives one seed per request (default
+    ``seed + 17*g``, matching what the ``independent`` schedule policy
+    always used); ``fixed_x`` one pinned push matrix per request for the
+    shuffle-only modes.  Results are per-request :class:`PlanResult`\\ s,
+    identical (to float32 vmap round-off) to calling
+    :func:`optimize_plan` per request.
 
     Span ``geoplan.plan_many`` covers the call, with attrs ``requests``
     and the transfers it made each way (``plan.h2d``, ``plan.d2h``) and
@@ -1292,17 +1242,18 @@ def _solve_joint_batch(
     tau1_frac: float = 1e-3,
 ):
     """Anneal all jobs' stacked plans jointly against shared-capacity
-    pricing; return per-restart (x, y) stacks plus their exact hard-gate
-    aggregate objective values."""
+    pricing; return per-restart (x, y) stacks plus their exact aggregate
+    objective values: hard max, but the smooth usage gate (the final
+    selection re-prices in float64)."""
 
-    def aggregate(x, y, mx, pmax, kap):
+    def aggregate(x, y, mx, pmax):
         # one vmapped instance of the volume/pricing graph regardless of J
         # (a per-job python loop here makes XLA compile time linear in the
         # job count — see _stacked_effective_volumes)
         vols = jax.vmap(
             lambda D, xg, yg, a: analytic_volumes(D, xg, yg, a, xp=jnp)
         )(D_stack, x, y, alpha_stack)
-        eff = _stacked_effective_volumes(vols, kap)
+        eff = _stacked_effective_volumes(vols, kappa)
         spans = jax.vmap(
             lambda v: volume_model(*v, B_sm, B_mr, C_m, C_r, barriers, mx,
                                    pmax, xp=jnp)["makespan"]
@@ -1311,25 +1262,8 @@ def _solve_joint_batch(
             spans = spans / refs * scale  # keep the tau schedule's units
         return mx(spans)
 
-    def loss(params, tau):
-        mx, pmax = smooth_ops(tau)
-        x = jax.nn.softmax(params["x"], axis=-1)
-        y = jax.nn.softmax(params["y"], axis=-1)
-        return aggregate(x, y, mx, pmax, kappa) / scale
-
-    def one_restart(lx0, ly0):
-        params = _adam_anneal(
-            loss, {"x": lx0, "y": ly0}, steps, scale, lr, tau0_frac, tau1_frac
-        )
-        x = jax.nn.softmax(params["x"], axis=-1)
-        y = jax.nn.softmax(params["y"], axis=-1)
-        mx, pmax = hard_ops()
-        # hard max, but the smooth usage gate (a hard gate kills the
-        # gradient-free comparison too): final selection re-prices in f64
-        exact = aggregate(x, y, mx, pmax, kappa)
-        return x, y, exact
-
-    return jax.vmap(one_restart)(logits_x0, logits_y0)
+    return _annealed(aggregate, logits_x0, logits_y0, scale, steps, lr,
+                     tau0_frac, tau1_frac)
 
 
 def _stacked_effective_volumes(vols, kappa: float, xp=jnp, bg=None):
@@ -1507,23 +1441,8 @@ def _solve_residual_batch_many(
             return volume_model(*V, *caps_b, barriers, mx, pmax,
                                 xp=jnp)["makespan"]
 
-        def loss(params, tau):
-            mx, pmax = smooth_ops(tau)
-            x = jax.nn.softmax(params["x"], axis=-1)
-            y = jax.nn.softmax(params["y"], axis=-1)
-            return residual_span(x, y, mx, pmax) / sc
-
-        def one_restart(lx0, ly0):
-            params = _adam_anneal(
-                loss, {"x": lx0, "y": ly0}, steps, sc, lr, tau0_frac,
-                tau1_frac,
-            )
-            x = jax.nn.softmax(params["x"], axis=-1)
-            y = jax.nn.softmax(params["y"], axis=-1)
-            mx, pmax = hard_ops()
-            return x, y, residual_span(x, y, mx, pmax)
-
-        return jax.vmap(one_restart)(lx_b, ly_b)
+        return _annealed(residual_span, lx_b, ly_b, sc, steps, lr,
+                         tau0_frac, tau1_frac)
 
     return jax.vmap(one_request)(
         resid, caps, alpha, logits_x0, logits_y0, scale
@@ -1831,40 +1750,26 @@ def _solve_residual_shared_batch(
     job's remaining work under its candidate plan, contention-inflated by
     the other jobs' residual demand (:func:`shared_effective_volumes`) and
     priced through the shared phase equations — the schedule analogue of
-    :func:`_solve_residual_batch`."""
+    :func:`_solve_residual_batch_many`.  The exact objective is a hard
+    max over the smooth usage gate; the final selection re-prices in
+    float64."""
 
-    def aggregate(x, y, mx, pmax, kap):
+    def aggregate(x, y, mx, pmax):
         # one vmapped instance of the volume/pricing graph regardless of J
         # (a per-job python loop here makes XLA compile time linear in the
         # live-job count — minutes at the 1000-node/100-job tier)
         vols = jax.vmap(
             lambda r, a, xg, yg: residual_volumes(*r, a, xg, yg, xp=jnp)
         )(resid_stack, alpha_stack, x, y)
-        eff = _stacked_effective_volumes(vols, kap, bg=bg_stack)
+        eff = _stacked_effective_volumes(vols, kappa, bg=bg_stack)
         spans = jax.vmap(
             lambda v, c: volume_model(*v, *c, barriers, mx, pmax,
                                       xp=jnp)["makespan"]
         )(eff, caps_stack)
         return mx(spans)
 
-    def loss(params, tau):
-        mx, pmax = smooth_ops(tau)
-        x = jax.nn.softmax(params["x"], axis=-1)
-        y = jax.nn.softmax(params["y"], axis=-1)
-        return aggregate(x, y, mx, pmax, kappa) / scale
-
-    def one_restart(lx0, ly0):
-        params = _adam_anneal(
-            loss, {"x": lx0, "y": ly0}, steps, scale, lr, tau0_frac, tau1_frac
-        )
-        x = jax.nn.softmax(params["x"], axis=-1)
-        y = jax.nn.softmax(params["y"], axis=-1)
-        mx, pmax = hard_ops()
-        # hard max, smooth usage gate; final selection re-prices in f64
-        exact = aggregate(x, y, mx, pmax, kappa)
-        return x, y, exact
-
-    return jax.vmap(one_restart)(logits_x0, logits_y0)
+    return _annealed(aggregate, logits_x0, logits_y0, scale, steps, lr,
+                     tau0_frac, tau1_frac)
 
 
 def _degraded_caps(substrate, progress: JobProgress):
@@ -2656,22 +2561,8 @@ def _solve_pipeline_batch(
             finish[k] = start + out["makespan"]
         return mx(jnp.stack(finish))
 
-    def loss(params, tau):
-        mx, pmax = smooth_ops(tau)
-        x = jax.nn.softmax(params["x"], axis=-1)
-        y = jax.nn.softmax(params["y"], axis=-1)
-        return pipeline_span(x, y, mx, pmax) / scale
-
-    def one_restart(lx0, ly0):
-        params = _adam_anneal(
-            loss, {"x": lx0, "y": ly0}, steps, scale, lr, tau0_frac, tau1_frac
-        )
-        x = jax.nn.softmax(params["x"], axis=-1)
-        y = jax.nn.softmax(params["y"], axis=-1)
-        mx, pmax = hard_ops()
-        return x, y, pipeline_span(x, y, mx, pmax)
-
-    return jax.vmap(one_restart)(logits_x0, logits_y0)
+    return _annealed(pipeline_span, logits_x0, logits_y0, scale, steps, lr,
+                     tau0_frac, tau1_frac)
 
 
 @register_pipeline_planner("end_to_end")

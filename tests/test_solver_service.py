@@ -18,6 +18,7 @@ from repro.core import (
     SolveTimeEMA,
     get_online_config,
     get_online_policy,
+    local_push_plan,
     optimize_plan,
     optimize_plan_batch,
     replan,
@@ -151,7 +152,8 @@ class TestBatchedEquivalence:
     OPTS = dict(n_restarts=4, steps=10)
 
     @pytest.mark.parametrize("mode", ["e2e_multi", "myopic_multi",
-                                      "e2e_push"])
+                                      "e2e_push", "myopic_push",
+                                      "e2e_shuffle"])
     def test_plan_batch_matches_sequential(self, mode):
         plats = [_platform(2, alpha=a, seed=s)
                  for s, a in enumerate((0.5, 1.0, 2.0))]
@@ -164,6 +166,22 @@ class TestBatchedEquivalence:
             np.testing.assert_allclose(b.plan.x, solo.plan.x, atol=1e-6)
             np.testing.assert_allclose(b.plan.y, solo.plan.y, atol=1e-6)
             assert b.makespan == pytest.approx(solo.makespan, rel=1e-4)
+
+    def test_pinned_fixed_x_is_the_plans_x(self):
+        """``e2e_shuffle`` with a pinned ``fixed_x`` returns exactly that
+        push matrix, one request at a time and batched."""
+        plats = [_platform(2, alpha=a, seed=s)
+                 for s, a in enumerate((0.5, 1.0, 2.0))]
+        seeds = [21, 22, 23]
+        pinned = [np.asarray(local_push_plan(p).x) for p in plats]
+        batch = optimize_plan_batch(plats, "e2e_shuffle",
+                                    barriers=BARRIERS_GGL, seeds=seeds,
+                                    fixed_x=pinned, **self.OPTS)
+        for p, s, fx, b in zip(plats, seeds, pinned, batch):
+            solo = optimize_plan(p, "e2e_shuffle", barriers=BARRIERS_GGL,
+                                 seed=s, fixed_x=fx, **self.OPTS)
+            np.testing.assert_array_equal(solo.plan.x, fx)
+            np.testing.assert_array_equal(b.plan.x, fx)
 
     def test_replan_batch_matches_sequential(self):
         plats = [_platform(2, alpha=1.0, seed=s) for s in (0, 1, 2)]
